@@ -17,6 +17,10 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from . import (
         bench_baselines,
         bench_batch,

@@ -260,32 +260,67 @@ def _partial_ksps(
 
 
 def _k_best_joins(segments: list[list[tuple[float, tuple]]], k: int):
-    """k best simple concatenations, one entry per segment (lazy heap)."""
+    """k best simple concatenations, one entry per segment.
+
+    Segment j's entries run from joint v_j to v_j+1, so a join is simple
+    exactly when the joints are distinct and the entries' interiors are
+    simple, avoid every joint and are pairwise disjoint.  The search is
+    best-first over prefixes, keyed by the prefix's length plus, for
+    every remaining segment, its shortest entry that avoids the vertices
+    the prefix uses (forward checking: an admissible bound, so complete
+    joins pop in ascending length, and a prefix that leaves some segment
+    no usable entry is dropped at once).  Enumerating whole index tuples
+    instead visits up to (entries per segment)^m of them when few joins
+    are simple, which long references (m ≈ 30 on a 36×36 grid) make
+    unbounded in practice.
+    """
     m = len(segments)
     if any(not seg for seg in segments):
         return []
-    first = tuple([0] * m)
-    start_d = sum(seg[0][0] for seg in segments)
-    heap = [(start_d, first)]
-    visited = {first}
+    joints = [seg[0][1][0] for seg in segments] + [segments[-1][0][1][-1]]
+    if len(set(joints)) != len(joints):
+        return []
+    joint_set = set(joints)
+    opts = []  # per segment: ascending [(d, interior set, path)]
+    for seg in segments:
+        keep = []
+        for d, p in sorted(seg, key=lambda e: e[0]):
+            inner = set(p[1:-1])
+            if len(inner) == len(p) - 2 and joint_set.isdisjoint(inner):
+                keep.append((d, frozenset(inner), p))
+        if not keep:
+            return []
+        opts.append(keep)
+
+    def bound(j, used):
+        """Least the segments j.. can add next to ``used``; None if one
+        of them has no usable entry."""
+        total = 0.0
+        for seg in opts[j:]:
+            best = next((d for d, inner, _ in seg if used.isdisjoint(inner)),
+                        None)
+            if best is None:
+                return None
+            total += best
+        return total
+
+    heap = [(bound(0, frozenset()), (), 0.0, frozenset())]
     out = []
     while heap and len(out) < k:
-        d, idx = heapq.heappop(heap)
-        # join the paths: consecutive segments share their joint vertex
-        verts: list[int] = []
-        ok = True
-        for j in range(m):
-            p = segments[j][idx[j]][1]
-            verts.extend(p if j == 0 else p[1:])
-        if len(set(verts)) == len(verts):
-            out.append((d, tuple(verts)))
-        for j in range(m):
-            if idx[j] + 1 < len(segments[j]):
-                nxt = idx[:j] + (idx[j] + 1,) + idx[j + 1 :]
-                if nxt not in visited:
-                    visited.add(nxt)
-                    nd = d - segments[j][idx[j]][0] + segments[j][idx[j] + 1][0]
-                    heapq.heappush(heap, (nd, nxt))
+        _, idx, g, used = heapq.heappop(heap)
+        j = len(idx)
+        if j == m:
+            verts = list(opts[0][idx[0]][2])
+            for jj in range(1, m):
+                verts.extend(opts[jj][idx[jj]][2][1:])
+            out.append((g, tuple(verts)))
+            continue
+        for i, (d, inner, _) in enumerate(opts[j]):
+            if used.isdisjoint(inner):
+                nxt = used | inner
+                h = bound(j + 1, nxt)
+                if h is not None:
+                    heapq.heappush(heap, (g + d + h, idx + (i,), g + d, nxt))
     return out
 
 

@@ -31,22 +31,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax ≥ 0.6 promoted shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 from repro.engine.dense import INF, bf_parents_grouped
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        # older jax: while_loop has no replication rule under check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax dropped check_rep (vma typing handles it)
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    # check_vma=False: a compiled pallas_call's out_shape carries no vma
+    # (varying-manual-axes) type, which the checked mode refuses
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _axis_size(axis):

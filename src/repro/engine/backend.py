@@ -12,9 +12,9 @@ backend object":
 * :class:`PallasBackend` — a fixed-point ``lax.while_loop`` over the
   fused ``kernels.bf_relax`` Pallas kernel (128-lane slabs, VMEM-
   bounded J buckets), with parents recovered post-convergence by the
-  same ``bf_parents_grouped`` the jnp path uses.  On non-TPU hosts the
-  kernel auto-falls back to ``interpret=True`` so the whole suite runs
-  without a TPU.
+  same ``bf_parents_grouped`` the jnp path uses.  The kernel runs
+  compiled on a TPU and interpreted on the CPU (where the tests run);
+  any other platform is an error (``kernels.ops.interpret_mode``).
 
 Both backends implement the same contract —
 
@@ -178,19 +178,21 @@ def _pallas_grouped_solver(S, J, z, interpret, donate=False):
         return dist, parent
 
     if donate:
-        # per-round scratch only (init + masks + caps): the fixed-point
-        # outputs reuse their device memory instead of re-allocating
-        return jax.jit(run, donate_argnums=(1, 2, 3, 4, 5))
+        # per-round scratch only: the fixed-point outputs reuse its
+        # device memory instead of re-allocating
+        from .yen_engine import DONATED_ARGS
+
+        return jax.jit(run, donate_argnums=DONATED_ARGS)
     return jax.jit(run)
 
 
 class PallasBackend(SolverBackend):
     """The Pallas ``bf_relax`` kernel iterated to its fixed point.
 
-    ``interpret=None`` (default) auto-detects: the kernel runs compiled
-    on TPU backends and in interpret mode everywhere else, so the same
-    engine spec serves on a laptop and a v5e pod.  Pass ``True``/
-    ``False`` to force either (tests force ``True`` for parity runs).
+    ``interpret=None`` (default) follows the platform: compiled on a
+    TPU, interpreted on the CPU, an error anywhere else
+    (``kernels.ops.interpret_mode``).  Pass ``True``/``False`` to force
+    either (tests force ``True`` for parity runs).
     """
 
     name = "pallas"
@@ -202,9 +204,12 @@ class PallasBackend(SolverBackend):
         self.donate = donate
 
     @property
-    def _interpret(self) -> bool:
+    def interpreted(self) -> bool:
+        """Whether the kernel runs in interpret mode on this process."""
         if self.interpret is None:
-            return jax.default_backend() != "tpu"
+            from repro.kernels.ops import interpret_mode
+
+            return interpret_mode()
         return bool(self.interpret)
 
     def solve_grouped(self, adj, init, banned_v, spur_onehot, banned_next,
@@ -212,11 +217,11 @@ class PallasBackend(SolverBackend):
         S, J, z = init.shape
         t0 = obs.clock()
         out = _pallas_grouped_solver(
-            S, J, z, self._interpret, donate=self._donate
+            S, J, z, self.interpreted, donate=self._donate
         )(adj, init, banned_v, spur_onehot, banned_next, cap)
         obs.span_at("solve_grouped", t0, obs.clock() - t0,
                     backend=self.name, S=S, J=J, z=z,
-                    interpret=self._interpret)
+                    interpret=self.interpreted)
         return out
 
     def mesh_relax(self):
@@ -224,7 +229,7 @@ class PallasBackend(SolverBackend):
 
         from .dense import INF
 
-        interpret = self._interpret
+        interpret = self.interpreted
 
         def prep(so, bn):
             return so.astype(jnp.float32), bn.astype(jnp.float32)

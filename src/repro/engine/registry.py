@@ -12,7 +12,7 @@ cache-miss refine tasks, and how to build a device-mesh solver — and
 the registry maps names to specs.  ``repro.service`` re-exports this
 module as the public way to plug in an engine; the builtin specs are
 ``pyen`` (host Yen), ``dense_bf`` (jnp grouped BF) and ``pallas_bf``
-(the fused Pallas kernel, interpret-mode on non-TPU hosts).
+(the fused Pallas kernel: compiled on a TPU, interpreted on the CPU).
 
 A spec's ``refine(worker, misses, k, epoch)`` receives the worker (slab,
 row_of, dtlp access), the cache-miss task list ``[(gid, a, b)]`` with
@@ -276,8 +276,9 @@ register_engine(EngineSpec(
 ))
 
 # PallasBackend layout packs at lane=128 with sublane-aligned,
-# VMEM-bounded J buckets; on non-TPU hosts the kernel runs interpret=True
-# and produces byte-identical paths to dense_bf
+# VMEM-bounded J buckets; on the CPU the kernel runs interpret=True (any
+# other non-TPU platform raises) and produces byte-identical paths to
+# dense_bf
 _PALLAS_BACKEND = PallasBackend()
 register_engine(EngineSpec(
     name="pallas_bf",
@@ -287,5 +288,5 @@ register_engine(EngineSpec(
     backend=_PALLAS_BACKEND,
     make_mesh_solver=_grouped_mesh_solver(_PALLAS_BACKEND),
     description="fused Pallas bf_relax fixed point over 128-lane slabs "
-                "(interpret-mode fallback off-TPU)",
+                "(interpreted on the CPU)",
 ))

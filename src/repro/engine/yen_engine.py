@@ -58,6 +58,11 @@ def _jit_solver(P, z):
     return run
 
 
+# (init, bv) of a grouped solve's (adj, init, bv, so, bn, cap): the two
+# round buffers XLA can reuse
+DONATED_ARGS = (1, 2)
+
+
 @functools.lru_cache(maxsize=None)
 def grouped_solver(S, J, z, donate: bool = False):
     """Shape-bucketed jitted grouped (solve + parents) over the
@@ -66,13 +71,15 @@ def grouped_solver(S, J, z, donate: bool = False):
     dense worker path (repro.dist.grouped_yen) dispatches through this;
     callers bucket S and J so varying batch shapes reuse compilations.
 
-    ``donate=True`` marks every per-round scratch buffer (all arguments
-    except the adjacency) as donated via ``donate_argnums``, so on
-    device backends XLA reuses their memory for the [S, J, z] outputs
-    instead of allocating fresh — the recopy-avoidance half of the async
-    pipeline.  Callers must only donate buffers packed fresh for the
-    round (``SlabLayout.pack_round`` guarantees this); donation is a
-    no-op on CPU, where backends leave it off.
+    ``donate=True`` donates the per-round ``init`` and ``bv`` buffers
+    via ``donate_argnums``, so on device backends XLA reuses their
+    memory (``init`` becomes the ``dist`` output) instead of allocating
+    fresh — the recopy-avoidance half of the async pipeline.  The other
+    masks and the caps have no output of their dtype and shape to take,
+    and donating them only makes XLA warn on every bucket compile.
+    Callers must only donate buffers packed fresh for the round
+    (``SlabLayout.pack_round`` guarantees this); donation is a no-op on
+    CPU, where backends leave it off.
     """
 
     def run(adj, init, bv, so, bn, cap):
@@ -81,7 +88,7 @@ def grouped_solver(S, J, z, donate: bool = False):
         return dist, parent
 
     if donate:
-        return jax.jit(run, donate_argnums=(1, 2, 3, 4, 5))
+        return jax.jit(run, donate_argnums=DONATED_ARGS)
     return jax.jit(run)
 
 

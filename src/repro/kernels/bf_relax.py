@@ -6,15 +6,27 @@ width TV:
     new[j, t] = clamp_cap( min( dist[j, t],
                   min_u  dist[j, u] + adj[u, t]  (spur-row cuts applied) ) )
 
-Memory plan (TPU v5e, 16 MiB VMEM/core):
-    dist tile     [J, z]    f32   J≤32, z≤1024  → ≤128 KiB
-    adj tile      [z, TV]   f32   z≤1024, TV=128 → 512 KiB
-    contrib       [J, z, TV] f32 intermediate   → ≤16 MiB at J=32,z=1024?
-      — no: the u-reduction is BLOCKED over z in chunks of UZ=256 so the
-      live intermediate is [J, UZ, TV] ≤ 4 MiB.
+Memory plan (TPU v5e, 16 MiB scoped VMEM limit per kernel):
+    dist block    [J, z]    f32   J≤32, z≤1024  → ≤128 KiB
+    adj block     [z, TV]   f32   z≤1024, TV=128 → 512 KiB
+    The u-reduction is a ``fori_loop`` over 128-aligned chunks of z, so
+    only one chunk's [J, UZ, TV] intermediate (≤ 2 MiB) is live at a
+    time; a statically unrolled loop keeps every chunk live (a 30.46 MiB
+    scoped allocation at J=32, z=1024 with UZ=256, over the limit).
+    The least ``vmem_limit_bytes`` the v5e compiler
+    accepts for this kernel (double-buffered blocks included): 0.63 MiB
+    at (J, z) = (8, 128), 2.73 MiB at (32, 128), 1.59 MiB at (8, 1024),
+    4.14 MiB at (32, 1024) — every bucket ``PALLAS_LAYOUT`` produces
+    (J ≤ 32) fits the default limit, and tests/test_chip_compile.py
+    compiles the largest.
     MXU is unused (tropical semiring has no matmul); this is a VPU
     min/add kernel and the roofline treats it as memory-bound, so tiles
     are chosen to stream adj exactly once per output tile.
+
+Mosaic tiling: every block's last two dims are (8, 128)-aligned or the
+full array dims, so ``cap`` travels as [S, J, 1]; the self tile of dist
+is an aligned ``pl.ds`` ref slice (``dynamic_slice`` has no TPU
+lowering inside a kernel).
 """
 
 from __future__ import annotations
@@ -28,37 +40,34 @@ from jax.experimental import pallas as pl
 INF = 3.0e38  # python float: jnp constants become captured consts in Pallas
 
 _TV = 128   # output vertex tile (lane dimension)
-_UZ = 256   # u-reduction chunk
+_UZ = 128   # u-reduction chunk
 
 
 def _bf_relax_kernel(dist_ref, adj_ref, spur_ref, ban_ref, cap_ref, out_ref):
     # dist_ref [1, J, z]; adj_ref [1, z, TV]; spur_ref [1, J, z];
-    # ban_ref [1, J, TV]; cap_ref [1, J]; out_ref [1, J, TV]
-    d = dist_ref[0]            # [J, z]
-    spur = spur_ref[0]         # [J, z] f32 0/1
-    ban = ban_ref[0]           # [J, TV] f32 0/1
-    cap = cap_ref[0]           # [J]
-    J, z = d.shape
+    # ban_ref [1, J, TV]; cap_ref [1, J, 1]; out_ref [1, J, TV]
+    J, z = dist_ref.shape[1], dist_ref.shape[2]
     TV = out_ref.shape[2]
+    ban = ban_ref[0]           # [J, TV] f32 0/1
 
-    best = jnp.full((J, TV), INF, jnp.float32)
-    n_chunks = z // _UZ if z % _UZ == 0 else (z + _UZ - 1) // _UZ
-    for c in range(n_chunks):  # static unroll: z known at trace time
-        u0 = c * _UZ
-        uz = min(_UZ, z - u0)
-        dc = jax.lax.dynamic_slice(d, (0, u0), (J, uz))        # [J, uz]
-        ac = jax.lax.dynamic_slice(adj_ref[0], (u0, 0), (uz, TV))
-        sc = jax.lax.dynamic_slice(spur, (0, u0), (J, uz))
-        contrib = dc[:, :, None] + ac[None, :, :]               # [J, uz, TV]
+    def chunk(c, best):
+        u0 = pl.multiple_of(c * _UZ, _UZ)
+        dc = dist_ref[0, :, pl.ds(u0, _UZ)]                     # [J, UZ]
+        ac = adj_ref[0, pl.ds(u0, _UZ), :]                      # [UZ, TV]
+        sc = spur_ref[0, :, pl.ds(u0, _UZ)]
+        contrib = dc[:, :, None] + ac[None, :, :]               # [J, UZ, TV]
         cut = (sc[:, :, None] * ban[:, None, :]) > 0.5
         contrib = jnp.where(cut, INF, contrib)
-        best = jnp.minimum(best, jnp.min(contrib, axis=1))
+        return jnp.minimum(best, jnp.min(contrib, axis=1))
+
+    best = jax.lax.fori_loop(0, z // _UZ, chunk,
+                             jnp.full((J, TV), INF, jnp.float32))
 
     # self tile of dist for the jnp.minimum(dist, ·) term
-    t = pl.program_id(1)
-    d_self = jax.lax.dynamic_slice(d, (0, t * TV), (J, TV))
+    t0 = pl.multiple_of(pl.program_id(1) * TV, TV)
+    d_self = dist_ref[0, :, pl.ds(t0, TV)]
     new = jnp.minimum(d_self, best)
-    new = jnp.where(new > cap[:, None], INF, new)
+    new = jnp.where(new > cap_ref[0], INF, new)
     out_ref[0] = new
 
 
@@ -96,10 +105,10 @@ def bf_relax(dist, adj, spur_onehot, banned_next, cap, *, interpret=False):
             pl.BlockSpec((1, z_pad, _TV), lambda s, t: (s, 0, t)),
             pl.BlockSpec((1, j_pad, z_pad), lambda s, t: (s, 0, 0)),
             pl.BlockSpec((1, j_pad, _TV), lambda s, t: (s, 0, t)),
-            pl.BlockSpec((1, j_pad), lambda s, t: (s, 0)),
+            pl.BlockSpec((1, j_pad, 1), lambda s, t: (s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, j_pad, _TV), lambda s, t: (s, 0, t)),
         out_shape=jax.ShapeDtypeStruct((S, j_pad, z_pad), jnp.float32),
         interpret=interpret,
-    )(dist, adj, spur_onehot, banned_next, cap)
+    )(dist, adj, spur_onehot, banned_next, cap[:, :, None])
     return out[:, :J, :z]

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True — the
-kernel body runs in Python for correctness validation; BlockSpecs target
-TPU v5e VMEM.  On real TPU backends interpret is off.
+On the CPU (where the tests run) the kernels execute with
+interpret=True — the kernel body runs in Python for correctness
+validation; BlockSpecs target TPU v5e VMEM.  On a TPU they run
+compiled.  Any other platform has no Pallas TPU lowering and raises.
 """
 
 from __future__ import annotations
@@ -17,8 +18,15 @@ from . import ktrop as _kt
 INF = _bf.INF
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Interpret on "cpu", compile on "tpu", raise on anything else."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels run compiled on 'tpu' or interpreted on "
+            f"'cpu'; the default JAX backend is {platform!r}"
+        )
+    return platform == "cpu"
 
 
 def bf_relax_step(dist, adj, spur_onehot, banned_next, cap=None):
@@ -32,14 +40,15 @@ def bf_relax_step(dist, adj, spur_onehot, banned_next, cap=None):
         spur_onehot.astype(jnp.float32),
         banned_next.astype(jnp.float32),
         cap.astype(jnp.float32),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
 
 
 def ktrop_relax_step(D, adj):
     """One k-distinct tropical relaxation (see kernels/ktrop.py)."""
     return _kt.ktrop_relax(
-        D.astype(jnp.float32), adj.astype(jnp.float32), interpret=_interpret()
+        D.astype(jnp.float32), adj.astype(jnp.float32),
+        interpret=interpret_mode(),
     )
 
 
@@ -51,5 +60,5 @@ def bound_dist_blocked(w_sorted, n_sorted, cum_before, sub_blocked, phi):
         cum_before.astype(jnp.float32),
         sub_blocked.astype(jnp.int32),
         phi.astype(jnp.float32),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )

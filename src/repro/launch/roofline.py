@@ -123,8 +123,6 @@ class Roofline:
 
 def extract_roofline(compiled, n_devices: int) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

@@ -15,11 +15,15 @@ barrier, and every answer reports the graph epoch that served it.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
+import jax
 import numpy as np
 
 from repro.data.roadnet import WeightUpdateStream, grid_road_network
+from repro.engine.backend import PallasBackend
+from repro.launch.compile_cache import use_compile_cache
 from repro.service import (
     VARIANTS,
     BoundedKSPRequest,
@@ -31,6 +35,7 @@ from repro.service import (
     UpdateBatch,
     available_engines,
     available_ref_streams,
+    get_engine,
 )
 
 
@@ -53,8 +58,8 @@ def main():
     ap.add_argument(
         "--engine", choices=available_engines(), default="pyen",
         help="refine engine spec: pyen (host Yen), dense_bf (jnp grouped "
-        "BF), pallas_bf (fused Pallas kernel; interpret-mode off-TPU — "
-        "identical answers to dense_bf)",
+        "BF), pallas_bf (fused Pallas kernel; compiled on a TPU, "
+        "interpreted on the CPU — identical answers to dense_bf)",
     )
     ap.add_argument(
         "--ref-stream", choices=available_ref_streams(), default=None,
@@ -154,6 +159,7 @@ def main():
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.trace:
         from repro import obs
@@ -166,20 +172,19 @@ def main():
         from repro.launch.mesh import init_distributed
 
         if init_distributed():
-            import jax
-
             print(f"jax.distributed initialized: process "
                   f"{jax.process_index()}/{jax.process_count()}, "
                   f"{jax.device_count()} global devices")
         else:
             print("--distributed: no coordinator configured; "
                   "continuing single-process with local devices")
+    dev = jax.devices()[0]
+    spec = get_engine(engine)
+    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}"
+          + (f" | pallas kernel "
+             f"{'interpreted' if spec.backend.interpreted else 'compiled'}"
+             if isinstance(spec.backend, PallasBackend) else ""))
     if args.mesh:
-        import jax
-
-        from repro.service import get_engine
-
-        spec = get_engine(engine)
         if not spec.supports_mesh:
             meshable = [e for e in available_engines()
                         if get_engine(e).supports_mesh]
@@ -300,15 +305,15 @@ def main():
     if svc.resyncs:
         print(f"stale-replica re-syncs: {svc.resyncs} "
               f"(revived workers replayed missed batches before serving)")
-    if total_empty:
-        print(f"WARNING: {total_empty} queries returned no paths")
     if args.trace:
         from repro import obs
 
         n_events = obs.export(args.trace)
         print(f"trace: {n_events} events → {args.trace} "
               f"(open at https://ui.perfetto.dev)")
-    print("serving run complete — non-truncated queries exact against their epoch")
+    if total_empty:
+        sys.exit(f"FAILED: {total_empty} queries returned no paths")
+    print("serving run complete")
 
 
 if __name__ == "__main__":

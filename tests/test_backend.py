@@ -177,6 +177,27 @@ class TestBackendParity:
                            backend=PallasBackend(interpret=True)) == []
 
 
+class TestInterpretRule:
+    """Pallas runs interpreted on the CPU, compiled on a TPU, and any
+    other platform is an error rather than a silent fallback."""
+
+    @pytest.mark.parametrize("platform,interpreted",
+                             [("cpu", True), ("tpu", False)])
+    def test_platform_picks_mode(self, monkeypatch, platform, interpreted):
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert PallasBackend().interpreted is interpreted
+
+    def test_other_platform_raises(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            PallasBackend().interpreted
+
+    def test_forced_mode_wins(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        assert PallasBackend(interpret=True).interpreted is True
+        assert PallasBackend(interpret=False).interpreted is False
+
+
 class TestPallasEngineEndToEnd:
     """Tier-1 serving scenario: queries + an UpdateBatch epoch barrier,
     ``pallas_bf`` (interpret on CPU) vs ``dense_bf`` — byte-identical

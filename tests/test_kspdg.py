@@ -226,3 +226,61 @@ def test_directed_graph_kspdg():
         got = ksp_dg(d, s, t, 3)
         want = ksp(view, s, t, 3, directed=True)
         assert [round(x, 8) for x, _ in got] == [round(x, 8) for x, _ in want]
+
+
+class TestKBestJoins:
+    """The splice of per-pair partial KSPs into whole candidates."""
+
+    @staticmethod
+    def brute_force(segments, k):
+        import itertools
+
+        out = []
+        for combo in itertools.product(*segments):
+            verts = list(combo[0][1])
+            for _, p in combo[1:]:
+                verts.extend(p[1:])
+            if len(set(verts)) == len(verts):
+                out.append((sum(d for d, _ in combo), tuple(verts)))
+        return sorted(out)[:k]
+
+    @staticmethod
+    def random_segments(rng, m, width, n_vertices):
+        """m chained segments of ≤ width entries each, ascending, with
+        random detours that often collide with other segments."""
+        joints = rng.choice(n_vertices, m + 1, replace=False)
+        segments = []
+        for a, b in zip(joints, joints[1:]):
+            entries = set()
+            while len(entries) < width:
+                mid = rng.choice(n_vertices, rng.integers(0, 3),
+                                 replace=False)
+                p = (int(a), *(int(v) for v in mid if v not in (a, b)),
+                     int(b))
+                entries.add((float(rng.integers(1, 20)), p))
+            segments.append(sorted(entries))
+        return segments
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_matches_brute_force(self, seed):
+        from repro.core.kspdg import _k_best_joins
+
+        rng = np.random.default_rng(seed)
+        segs = self.random_segments(rng, int(rng.integers(1, 6)), 3, 14)
+        got = _k_best_joins(segs, 3)
+        want = self.brute_force(segs, 3)
+        assert [d for d, _ in got] == [d for d, _ in want]
+        assert all(len(set(p)) == len(p) for _, p in got)
+
+    def test_no_simple_join_returns_without_enumerating(self):
+        """Every entry of the first segment runs through every detour of
+        the second, so no join is simple: the answer is [] after the
+        two-segment prefixes, not after 3**30 index tuples."""
+        from repro.core.kspdg import _k_best_joins
+
+        m = 30
+        detours = (2000 * m, 2000 * m + 1, 2000 * m + 2)
+        segs = [[(1.0, (0, *detours, 100 + i, m)) for i in range(3)]]
+        segs += [[(float(i + 1), (j, 2000 * j + i, j + 1)) for i in range(3)]
+                 for j in range(m, 2 * m - 1)]
+        assert _k_best_joins(segs, 3) == []

@@ -141,33 +141,6 @@ class TestMLADecodeParity:
         )
 
 
-class TestKernelVMEMBudget:
-    def test_blockspec_tiles_fit_v5e_vmem(self):
-        """Static check: the VMEM working set each kernel claims per grid
-        step fits a v5e core (16 MiB), at the largest supported shapes."""
-        VMEM = 16 * 2**20
-        # bf_relax at z=1024, J=32: dist[J,z] + adj[z,TV] + spur[J,z]
-        # + ban[J,TV] + out[J,TV] + contrib chunk [J,UZ,TV]
-        from repro.kernels.bf_relax import _TV, _UZ
-
-        J, z = 32, 1024
-        working = 4 * (J * z + z * _TV + J * z + J * _TV + J * _TV
-                       + J * _UZ * _TV)
-        assert working < VMEM, f"bf_relax working set {working/2**20:.1f} MiB"
-        # ktrop at k=16, z=1024
-        from repro.kernels.ktrop import _TV as TV2, _UZ as UZ2
-
-        k = 16
-        working = 4 * (k * z + z * TV2 + k * TV2 + k * UZ2 * TV2 + 2 * TV2)
-        assert working < VMEM, f"ktrop working set {working/2**20:.1f} MiB"
-        # bound_dist at E=8192, TB=256
-        from repro.kernels.bound_dist import _TB
-
-        E = 8192
-        working = 4 * (3 * E + 2 * _TB + _TB * E)
-        assert working < VMEM, f"bound_dist working set {working/2**20:.1f} MiB"
-
-
 class TestMixedCache:
     def test_mixed_cache_decode_matches_stacked(self):
         """Per-layer mixed-window caches (local ring = window slots) must
