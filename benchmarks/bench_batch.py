@@ -21,8 +21,7 @@ and power-law path lengths — mostly small local queries with a heavy
 tail of big spans, like real navigation traffic.  Mixed sizes are where
 the lockstep tick stalled (every query waited on the slowest cohort's
 solve each round); the pipelined scheduler overlaps them, and the rows
-report what that buys — p50/p95 latency, per-worker idle fraction, and
-peak pipeline occupancy.
+report what that buys — p50/p95 latency and peak pipeline occupancy.
 
 ``--smoke`` doubles as the CI regression gate: it FAILS (exit 1) when
 dense_bf qps at concurrency 8 drops below 90% of concurrency 1 (best of
@@ -213,7 +212,6 @@ def bench_batch(quick=True, engine=None, smoke=False, mixed=False):
                 svc, tickets, total = best[c]
                 st = svc.scheduler.stats
                 lat = sorted(tk.result.latency_ms for tk in tickets)
-                idle = st.idle_fracs()
                 mixed_p50.setdefault(eng, {})[c] = lat[len(lat) // 2]
                 rows.append(
                     dict(
@@ -227,8 +225,6 @@ def bench_batch(quick=True, engine=None, smoke=False, mixed=False):
                         # peak dispatched-but-unfinished batches across
                         # all worker pipes (1 would mean lockstep)
                         occupancy=st.max_inflight_batches,
-                        idle_fracs={str(w): round(f, 4)
-                                    for w, f in idle.items()},
                         dedup_frac=round(
                             st.tasks_deduped / max(1, st.tasks_requested), 4
                         ),
@@ -280,7 +276,7 @@ if __name__ == "__main__":
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--mixed", action="store_true",
                     help="add the power-law mixed-size leg (fig="
-                    "batch_mixed: p50/p95, per-worker idle, occupancy)")
+                    "batch_mixed: p50/p95, occupancy)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI run that exercises the batched path and "
                     "fails on a c=8-vs-c=1 dense qps regression or a "

@@ -324,6 +324,49 @@ def _k_best_joins(segments: list[list[tuple[float, tuple]]], k: int):
     return out
 
 
+def _next_cohort(refs, pending, batch, ref_budget, global_of_ext,
+                 stats: QueryStats):
+    """Pull one cohort — ``pending`` and up to ``batch - 1`` more
+    references tied at its weight — and de-duplicate its refine pairs.
+
+    Returns ``(pending, pairs, ref_pairs)``: the stream's next unconsumed
+    reference, the cohort's ordered unique (a, b) pairs, and per simple
+    reference the indices of its pairs.  Tied references on a corridor
+    mostly cross the same boundary pairs, so the request (and the
+    grouped solve behind it) stays small.  Non-simple references
+    (lazy-stream walks revisiting a vertex) are consumed for the stop
+    rule but never refined: every join of a walk contains the walk's
+    full vertex sequence, so the repeated vertex makes every candidate
+    non-simple — refining one is pure waste.
+    """
+    cohort = [pending]
+    pending = next(refs, None)
+    while (pending is not None and len(cohort) < batch
+           and stats.references + len(cohort) < ref_budget
+           and pending[0] <= cohort[0][0] + TIE_EPS):
+        cohort.append(pending)
+        pending = next(refs, None)
+    stats.references += len(cohort)
+    pair_index: dict = {}
+    pairs: list[tuple] = []
+    ref_pairs: list[list[int]] = []
+    for _, ref_path_ext in cohort:
+        ref_path = [global_of_ext[v] for v in ref_path_ext]
+        if len(set(ref_path)) != len(ref_path):
+            stats.walks_skipped += 1
+            continue
+        idxs = []
+        for a, b in zip(ref_path, ref_path[1:]):
+            j = pair_index.get((a, b))
+            if j is None:
+                j = len(pairs)
+                pair_index[(a, b)] = j
+                pairs.append((a, b))
+            idxs.append(j)
+        ref_pairs.append(idxs)
+    return pending, pairs, ref_pairs
+
+
 @dataclasses.dataclass
 class RefineRequest:
     """One KSP-DG iteration's refine work, yielded by ``ksp_dg_stepper``.
@@ -387,6 +430,11 @@ def ksp_dg_stepper(
     (``finalize``); ``None`` is the plain top-k query.  Refine depth and
     :class:`RefineRequest.k` follow ``solve_k``, so the scheduler's
     cross-query dedup keys stay correct automatically.
+
+    Traced (``repro.obs``): each contiguous run of pulls from the stream
+    is a ``ref_stream`` span (attrs: ``references`` consumed,
+    ``walks_skipped``), and each iteration's joins into ``L`` a ``join``
+    span (``iteration``, ``pairs``).
     """
     policy = variant if variant is not None else _PLAIN
     solve_k = policy.solve_k(k)
@@ -401,8 +449,6 @@ def ksp_dg_stepper(
     # per-target sidetrack trees are reusable across queries only on the
     # un-spliced base skeleton (no home ⇒ no per-query extra vertices)
     tree_cache = dtlp.ref_tree_cache() if not home else None
-    refs = spec.factory(view, es, et, dtlp.graph.directed,
-                        tree_cache=tree_cache)
 
     L: list[tuple[float, tuple]] = []
     L_set = set()
@@ -412,89 +458,82 @@ def ksp_dg_stepper(
     # reference budget bounds raw stream consumption so a lazy stream
     # cannot spin forever skipping non-simple walks between refines
     ref_budget = max_iterations * batch
-    pending = next(refs, None)
-    while (pending is not None and stats.iterations < max_iterations
-           and stats.references < ref_budget):
-        cohort = [pending]
-        pending = next(refs, None)
-        while (pending is not None and len(cohort) < batch
-               and stats.references + len(cohort) < ref_budget
-               and pending[0] <= cohort[0][0] + TIE_EPS):
-            cohort.append(pending)
-            pending = next(refs, None)
-        stats.references += len(cohort)
-        # ordered de-dup of the cohort's refine pairs: tied references on
-        # a corridor mostly cross the same boundary pairs, so the request
-        # (and the grouped solve behind it) stays small.  Non-simple
-        # references (lazy-stream walks revisiting a vertex) are consumed
-        # for the stop rule but never refined: every join of a walk
-        # contains the walk's full vertex sequence, so the repeated
-        # vertex makes every candidate non-simple — refining one is pure
-        # waste.
-        pair_index: dict = {}
-        pairs: list[tuple] = []
-        ref_pairs: list[list[int]] = []
-        for _, ref_path_ext in cohort:
-            ref_path = [global_of_ext[v] for v in ref_path_ext]
-            if len(set(ref_path)) != len(ref_path):
-                stats.walks_skipped += 1
-                continue
-            idxs = []
-            for a, b in zip(ref_path, ref_path[1:]):
-                j = pair_index.get((a, b))
-                if j is None:
-                    j = len(pairs)
-                    pair_index[(a, b)] = j
-                    pairs.append((a, b))
-                idxs.append(j)
-            ref_pairs.append(idxs)
+    refs = pending = None
+    while True:
+        # one contiguous run of pulls from the stream, timed as one span:
+        # the stream's construction and priming pull, or the stop rule's
+        # scan past the last iteration; then, unless the query stops,
+        # the next cohort
+        with obs.span("ref_stream") as run:
+            pulled, skipped = stats.references, stats.walks_skipped
+            stop = False
+            if refs is None:
+                refs = spec.factory(view, es, et, directed,
+                                    tree_cache=tree_cache)
+                pending = next(refs, None)
+            else:
+                # the variant policy names the Theorem-3 bound: the
+                # weight at or below which the answer is already decided
+                # (L[k-1] for plain top-k; see core.variants for the
+                # bounded/diverse forms)
+                bound = policy.stop_bound(L, k, directed)
+                if pending is not None and bound is not None:
+                    # sharpened stop rule: only SIMPLE references can
+                    # ever seed a simple candidate (every join of a
+                    # repeated-vertex walk is itself non-simple), so the
+                    # binding Theorem-3 lower bound is the next simple
+                    # reference's weight, not the next raw walk's.
+                    # Skip-and-consume non-simple walks up to that
+                    # reference — or until any walk already outweighs
+                    # the bound, which certifies the stop on its own; the
+                    # reference budget bounds the scan on walk-dense tie
+                    # plateaus.
+                    while (pending is not None
+                           and stats.references < ref_budget
+                           and pending[0] <= bound + TIE_EPS):
+                        ref_path = [global_of_ext[v] for v in pending[1]]
+                        if len(set(ref_path)) == len(ref_path):
+                            break  # simple: its weight is the sharp bound
+                        stats.references += 1
+                        stats.walks_skipped += 1
+                        pending = next(refs, None)
+                    stop = (pending is None
+                            or policy.stop_at(bound, pending[0]))
+            if not stop:
+                if (pending is None or stats.iterations >= max_iterations
+                        or stats.references >= ref_budget):
+                    # the stream or a budget ran out before the stop rule
+                    # fired: best effort unless the stream is exhausted
+                    stats.truncated = pending is not None
+                    stop = True
+                else:
+                    pending, pairs, ref_pairs = _next_cohort(
+                        refs, pending, batch, ref_budget, global_of_ext,
+                        stats)
+            run.set(references=stats.references - pulled,
+                    walks_skipped=stats.walks_skipped - skipped)
+        if stop:
+            break
         if pairs:
             stats.iterations += 1
-            obs.event("ksp_iteration", s=s, t=t,
-                      iteration=stats.iterations, pairs=len(pairs),
-                      references=stats.references)
             seg_lists = yield RefineRequest(pairs=pairs, home=home,
                                             k=solve_k, stats=stats)
             if isinstance(seg_lists, dict):
                 # out-of-order delivery: per-worker pipelines answer in
                 # completion order, keyed by pair index — realign here
                 seg_lists = [seg_lists[j] for j in range(len(pairs))]
-            for idxs in ref_pairs:
-                for d, p in _k_best_joins([seg_lists[j] for j in idxs],
-                                          solve_k):
-                    if p not in L_set:
-                        L_set.add(p)
-                        L.append((d, p))
-            L.sort(key=lambda x: (x[0], x[1]))
-            for d_, p_ in L[solve_k:]:
-                L_set.discard(p_)
-            L = L[:solve_k]
-        # the variant policy names the Theorem-3 bound: the weight at or
-        # below which the answer is already decided (L[k-1] for plain
-        # top-k; see core.variants for the bounded/diverse forms)
-        bound = policy.stop_bound(L, k, directed)
-        if pending is not None and bound is not None:
-            # sharpened stop rule: only SIMPLE references can ever seed a
-            # simple candidate (every join of a repeated-vertex walk is
-            # itself non-simple), so the binding Theorem-3 lower bound is
-            # the next simple reference's weight, not the next raw
-            # walk's.  Skip-and-consume non-simple walks up to that
-            # reference — or until any walk already outweighs the bound,
-            # which certifies the stop on its own; the reference budget
-            # bounds the scan on walk-dense tie plateaus.
-            while (pending is not None
-                   and stats.references < ref_budget
-                   and pending[0] <= bound + TIE_EPS):
-                ref_path = [global_of_ext[v] for v in pending[1]]
-                if len(set(ref_path)) == len(ref_path):
-                    break  # simple: its weight is the sharp bound
-                stats.references += 1
-                stats.walks_skipped += 1
-                pending = next(refs, None)
-            if pending is None or policy.stop_at(bound, pending[0]):
-                break
-    else:
-        stats.truncated = pending is not None
+            with obs.span("join", iteration=stats.iterations,
+                          pairs=len(pairs)):
+                for idxs in ref_pairs:
+                    for d, p in _k_best_joins([seg_lists[j] for j in idxs],
+                                              solve_k):
+                        if p not in L_set:
+                            L_set.add(p)
+                            L.append((d, p))
+                L.sort(key=lambda x: (x[0], x[1]))
+                for d_, p_ in L[solve_k:]:
+                    L_set.discard(p_)
+                L = L[:solve_k]
     return policy.finalize(L, k, stats, directed), stats
 
 

@@ -67,12 +67,14 @@ def _dispatch_round(adj, jobs, solver, s_multiple, backend, gather=None):
 def _collect_round(pending):
     """Force a dispatched round to numpy: per-job (dist[z], parent[z])
     rows in job order.  This is where the host actually waits on the
-    device — everything between dispatch and collect overlapped."""
+    device — everything between dispatch and collect overlapped — and
+    the ``collect`` span times that wait."""
     if pending is None:
         return []
     dist, parent, slots = pending
-    dist = np.asarray(dist)
-    parent = np.asarray(parent)
+    with obs.span("collect", jobs=len(slots)):
+        dist = np.asarray(dist)
+        parent = np.asarray(parent)
     return [(dist[sr, j], parent[sr, j]) for sr, j in slots]
 
 

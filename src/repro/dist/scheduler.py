@@ -85,11 +85,15 @@ class BatchStats:
     # all pipes (≤ n_workers × pipeline_depth; 1 in lockstep mode where
     # exactly one batch is ever in flight)
     max_inflight_batches: int = 0
-    # wall seconds inside working (non-idle) ticks, and the share each
-    # worker spent actually being driven (dispatch + step + deliver):
-    # idle fraction of worker w = 1 - worker_busy_s[w] / working_s
+    # wall seconds inside working (non-idle) ticks, and per worker the
+    # wall seconds spent driving its batches (dispatch + step), which
+    # lumps host work together with the wait on the device
     working_s: float = 0.0
     worker_busy_s: dict = dataclasses.field(default_factory=dict)
+    # summed over finished queries' core QueryStats: reference paths
+    # consumed, and of them the non-simple walks never refined
+    references: int = 0
+    walks_skipped: int = 0
 
     @property
     def tasks_deduped(self) -> int:
@@ -102,15 +106,6 @@ class BatchStats:
         per-global-tick merge did in lockstep mode.
         """
         return self.tasks_requested - self.tasks_dispatched
-
-    def idle_fracs(self) -> dict:
-        """Per-worker idle fraction of working time (pipeline health)."""
-        if self.working_s <= 0.0:
-            return {}
-        return {
-            wid: max(0.0, 1.0 - busy / self.working_s)
-            for wid, busy in sorted(self.worker_busy_s.items())
-        }
 
 
 @dataclasses.dataclass
@@ -406,6 +401,8 @@ class QueryScheduler:
             tk._stepper = tk._request = None
             self.finished.append(tk)
             self.stats.completed += 1
+            self.stats.references += tk.stats.references
+            self.stats.walks_skipped += tk.stats.walks_skipped
 
     # -------------------------------------------------- pipelined serving
     def _stamp_clock(self) -> None:
@@ -528,8 +525,9 @@ class QueryScheduler:
         tk = pending.tk
         req = pending.req
         t0 = obs.clock()
-        seg_lists = merge_segments(req.pairs, pending.pair_gids,
-                                   pending.results, req.k)
+        with obs.span("merge", pairs=len(req.pairs)):
+            seg_lists = merge_segments(req.pairs, pending.pair_gids,
+                                       pending.results, req.k)
         req.stats.refine_tasks += len(req.pairs)
         tk.ticks += 1
         self._stamp_clock()
@@ -668,9 +666,10 @@ class QueryScheduler:
         for tk, pair_gids in gathered:
             req = tk._request
             ts0 = obs.clock()
-            seg_lists = merge_segments(req.pairs, pair_gids,
-                                       results.get((req.k, tk.epoch), {}),
-                                       req.k)
+            with obs.span("merge", pairs=len(req.pairs)):
+                seg_lists = merge_segments(
+                    req.pairs, pair_gids, results.get((req.k, tk.epoch), {}),
+                    req.k)
             req.stats.refine_tasks += len(req.pairs)
             tk.ticks += 1
             self._advance(tk, seg_lists)

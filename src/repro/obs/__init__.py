@@ -14,6 +14,10 @@ service — can instrument itself without cycles):
   ``worker_busy_s``) and the trace, one source of truth with no
   drift.  The collector exports Chrome-trace/Perfetto JSON
   (``obs.export(path)``) with one timeline per worker.
+  ``obs.clock_anchor()`` ties ``obs.clock`` to a running
+  ``jax.profiler`` capture (:func:`repro.obs.trace.fit_clock`), so the
+  spans can be laid over the device trace (``on_profile``) and name
+  what the host did in its gaps (``name_intervals``).
 * **Metrics** (:mod:`repro.obs.metrics`): counters / gauges /
   fixed-bucket mergeable histograms behind a :class:`MetricsRegistry`
   — always on (it replaces accounting the stack already did);
@@ -60,7 +64,15 @@ from .metrics import (  # noqa: F401
     jsonable,
 )
 from .recorder import FlightRecorder, track_name  # noqa: F401
-from .trace import Collector, Record  # noqa: F401
+from .trace import (  # noqa: F401
+    ANCHOR,
+    ClockFit,
+    Collector,
+    Record,
+    fit_clock,
+    name_intervals,
+    on_profile,
+)
 
 __all__ = [
     "clock",
@@ -73,6 +85,11 @@ __all__ = [
     "event",
     "traced",
     "worker_scope",
+    "clock_anchor",
+    "fit_clock",
+    "ClockFit",
+    "on_profile",
+    "name_intervals",
     "export",
     "flight_dump",
     "Collector",
@@ -262,6 +279,25 @@ class worker_scope:
     def __exit__(self, *exc):
         _STATE.tid = self._prev
         return False
+
+
+def clock_anchor() -> None:
+    """Record one clock anchor: the ``obs.clock`` bracket around an
+    empty ``jax.profiler.TraceAnnotation`` named ``repro.obs.anchor``.
+
+    Taken while a profiler capture runs, the anchors let
+    :func:`fit_clock` map this module's spans onto the capture's
+    nanoseconds.  Does nothing when disabled; jax is imported only
+    here, so ``repro.obs`` keeps no import-time dependency on it.
+    """
+    if not _STATE.enabled:
+        return
+    from jax.profiler import TraceAnnotation
+
+    t0 = clock()
+    with TraceAnnotation(ANCHOR):
+        pass
+    _STATE.collector.anchors.append((t0, clock()))
 
 
 def export(path: str) -> int:

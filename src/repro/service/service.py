@@ -188,7 +188,6 @@ class KSPService:
         self.registry.provider("scheduler", lambda: {
             **dataclasses.asdict(self.scheduler.stats),
             "tasks_deduped": self.scheduler.stats.tasks_deduped,
-            "idle_fracs": self.scheduler.stats.idle_fracs(),
             "tick_latency_ewma_ms": self.scheduler.tick_latency_ewma * 1e3,
         })
         self.registry.provider("workers", lambda: [
@@ -297,7 +296,7 @@ class KSPService:
         """One JSON-serializable view of every layer's accounting.
 
         Merges ``ServiceStats`` + scheduler ``BatchStats`` (with derived
-        idle fractions and dedup counts) + per-worker ``WorkerStats``
+        dedup counts) + per-worker ``WorkerStats``
         (resyncs, probation state included) + cluster routing counters +
         the live latency/lag histograms — the schema
         ``benchmarks/common.service_row`` flattens into bench rows and

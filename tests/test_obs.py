@@ -63,7 +63,7 @@ class TestSpanAPI:
 
     def test_event_is_instant(self):
         col = obs.enable(trace=True)
-        obs.event("ksp_iteration", iteration=1)
+        obs.event("marker", iteration=1)
         (r,) = col.events
         assert r.kind == "event" and r.dur == 0.0 and r.tid == 0
 
@@ -149,6 +149,146 @@ class TestDisabledNoop:
         assert len(col) == 1
 
 
+# ------------------------------------------------------ clock alignment
+class TestClockAnchor:
+    @staticmethod
+    def _brackets(n, start=1234.5, step=0.02, width=2e-6):
+        mids = [start + i * step for i in range(n)]
+        return mids, [(m - width / 2, m + width / 2) for m in mids]
+
+    def test_fit_recovers_offset_and_drift(self):
+        mids, brackets = self._brackets(400)
+        a, b = 1e9 * (1 + 40e-6), 1.7e18  # 40 ppm drift, epoch offset
+        ns = [int(round(b + a * m)) for m in mids]
+        fit = obs.fit_clock(brackets, ns)
+        assert fit.a == pytest.approx(a, rel=1e-9)
+        assert fit.residual_ns < 300  # float64 spacing near 1.7e18 is 256
+        for m, y in zip(mids[::37], ns[::37]):
+            assert abs(fit.ns(m) - y) < 300
+        assert abs(fit.ns(mids[-1] + 1.0) - (b + a * (mids[-1] + 1.0))) < 1e3
+
+    def test_fit_residual_reports_jitter(self):
+        mids, brackets = self._brackets(50)
+        jitter = [(-1) ** i * 3_000 for i in range(50)]  # +-3 us
+        ns = [int(1e9 * m) + 10**15 + j for m, j in zip(mids, jitter)]
+        fit = obs.fit_clock(brackets, ns)
+        assert 2_500 < fit.residual_ns < 3_500
+        assert fit.a == pytest.approx(1e9, rel=1e-6)
+
+    def test_one_anchor_fixes_the_offset(self):
+        fit = obs.fit_clock([(10.0, 10.0)], [777])
+        assert (fit.a, fit.residual_ns) == (1e9, 0.0)
+        assert fit.ns(10.5) == pytest.approx(777 + 5e8)
+
+    def test_count_mismatch_raises(self):
+        _, brackets = self._brackets(3)
+        with pytest.raises(ValueError, match="3 anchor brackets but 2"):
+            obs.fit_clock(brackets, [1, 2])
+        with pytest.raises(ValueError):
+            obs.fit_clock([], [])
+
+    def test_clock_anchor_records_a_bracket_only_when_enabled(self):
+        obs.clock_anchor()  # disabled: nothing to record, no error
+        col = obs.enable(trace=True)
+        obs.clock_anchor()
+        obs.clock_anchor()
+        assert len(col.anchors) == 2
+        (a0, a1), (b0, b1) = col.anchors
+        assert a0 <= a1 <= b0 <= b1
+        assert len(col.events) == 0  # anchors are not trace records
+
+
+# ------------------------------------------- spans on a profile's clock
+class TestProfileNaming:
+    # idle gaps [0, 100), [200, 600), [700, 1000); a splice holds a
+    # ref_stream run, and the caller's tick mark ends at 800
+    GAPS = [(0, 100), (200, 600), (700, 1000)]
+    TICK = [("tick", 0, 800)]
+
+    def test_gaps_split_by_innermost_span(self):
+        spans = [("splice", 250, 500), ("ref_stream", 300, 480)]
+        labels, totals = obs.name_intervals(self.GAPS, spans, self.TICK)
+        assert labels == ["tick", "ref_stream", "other"]
+        assert totals == {"tick": 350, "splice": 70, "ref_stream": 180,
+                          "other": 200}
+        assert sum(totals.values()) == sum(e - s for s, e in self.GAPS)
+
+    def test_marks_name_what_no_span_covers(self):
+        labels, totals = obs.name_intervals(self.GAPS, [], self.TICK)
+        assert labels == ["tick", "tick", "other"]
+        assert totals == {"tick": 600, "other": 200}
+        labels, totals = obs.name_intervals(self.GAPS, [])
+        assert labels == ["other"] * 3 and totals == {"other": 800}
+
+    def test_innermost_is_the_latest_started(self):
+        from repro.obs.trace import innermost
+
+        pieces = innermost([("solve", 0, 10), ("collect", 2, 5),
+                            ("dispatch_round", 6, 8)], [("tick", 0, 12)])
+        assert pieces == [(0, 2, "solve"), (2, 5, "collect"),
+                          (5, 6, "solve"), (6, 8, "dispatch_round"),
+                          (8, 10, "solve"), (10, 12, "tick")]
+
+    def test_on_profile_maps_and_filters(self):
+        fit = obs.ClockFit(a=1e9, t0=5.0, ns0=1_000.0, residual_ns=0.0)
+        recs = [Record("span", "queue_wait", 5.0, 0.5, 0, {}),
+                Record("span", "splice", 5.1, 0.2, 0, {}),
+                Record("event", "marker", 5.2, 0.0, 0, {}),
+                Record("span", "late", 9.0, 0.1, 0, {})]
+        spans = obs.on_profile(recs, fit, window=(0, 2e9))
+        ((name, s, e),) = spans
+        assert name == "splice"
+        assert (s, e) == (pytest.approx(1e8 + 1_000),
+                          pytest.approx(3e8 + 1_000))
+        assert [n for n, _, _ in obs.on_profile(recs, fit)] == [
+            "splice", "late"]
+
+    def test_cpu_profile_round_trip(self, tmp_path):
+        """An obs span around a profiler annotation lands around it on
+        the profile's clock, within the anchor fit's residual."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData, TraceAnnotation
+
+        col = obs.enable(trace=True)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            obs.clock_anchor()
+            for _ in range(20):
+                with obs.span("host_work"):
+                    with TraceAnnotation("tick"):
+                        time.sleep(0.002)
+                obs.clock_anchor()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        pd = ProfileData.from_file(path)
+        events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                  for plane in pd.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name in ("tick", obs.ANCHOR)]
+        anchors = sorted(s for n, s, _ in events if n == obs.ANCHOR)
+        ticks = sorted(e for e in events if e[0] == "tick")
+        assert len(anchors) == len(col.anchors) == 21
+        fit = obs.fit_clock(col.anchors, anchors)
+        spans = sorted(obs.on_profile(col.spans(), fit),
+                       key=lambda x: x[1])
+        assert len(spans) == len(ticks) == 20
+        tol = fit.residual_ns + 1_000
+        for (name, s, e), (_, ts, te) in zip(spans, ticks):
+            assert name == "host_work"
+            assert s - tol <= ts and te <= e + tol
+        labels, _ = obs.name_intervals([(ts, te) for _, ts, te in ticks],
+                                       spans)
+        assert set(labels) == {"host_work"}
+
+
 # ---------------------------------------------------------- chrome export
 class TestChromeExport:
     def _capture(self):
@@ -158,7 +298,7 @@ class TestChromeExport:
         obs.span_at("dispatch", t + 0.003, 0.001, worker=0)
         obs.span_at("solve", t + 0.004, 0.005, worker=0)
         obs.span_at("splice", t + 0.010, 0.001, qid=0)
-        obs.event("ksp_iteration", iteration=1)
+        obs.event("marker", iteration=1)
         return col
 
     def test_schema(self, tmp_path):
@@ -327,6 +467,31 @@ class TestServiceObs:
         # ... and the per-query spans carry their qids
         qids = {r.attrs["qid"] for r in col.spans("splice")}
         assert qids == {tk._ticket.qid for tk in tickets}
+
+        # the host phases inside them: reference-stream pulls at
+        # admission and in each splice, the joins and segment merges of
+        # each splice, and the host's wait on each device round
+        def inside(r, outer_names):
+            return any(o.tid == r.tid and o.ts <= r.ts
+                       and r.ts + r.dur <= o.ts + o.dur + 1e-9
+                       for name in outer_names for o in col.spans(name))
+
+        assert {"ref_stream", "join", "merge"} <= by_tid[0]
+        for name, outer in (("ref_stream", ("admit", "splice")),
+                            ("join", ("splice",)), ("merge", ("splice",)),
+                            ("collect", ("solve",))):
+            spans = col.spans(name)
+            assert spans, name
+            assert all(inside(r, outer) for r in spans), name
+        for tid in worker_tids:
+            assert "collect" in by_tid[tid]
+        runs = col.spans("ref_stream")
+        assert sum(r.attrs["references"] for r in runs) == sum(
+            tk.result.stats.references for tk in tickets)
+        assert sum(r.attrs["walks_skipped"] for r in runs) == sum(
+            tk.result.stats.walks_skipped for tk in tickets)
+        assert all(r.attrs["iteration"] >= 1 for r in col.spans("join"))
+        assert "ksp_iteration" not in {r.name for r in col.events}
 
         path = tmp_path / "t.json"
         assert obs.export(str(path)) == len(col.events)

@@ -1,8 +1,8 @@
 """Async pipelined scheduler: byte-identical determinism vs the lockstep
 reference schedule (including across an UpdateBatch epoch barrier with a
 mid-batch worker kill/revive), per-worker pipeline dedup accounting,
-idle/occupancy stats, and the sharpened next-simple-reference stop rule
-on a continuous-weight grid."""
+occupancy and reference-stream stats, and the sharpened
+next-simple-reference stop rule on a continuous-weight grid."""
 
 import numpy as np
 import pytest
@@ -130,21 +130,41 @@ class TestOutOfOrderDeterminism:
 
 
 class TestPipelineStats:
+    @pytest.mark.parametrize("pipeline", [False, True])
+    def test_reference_counters_sum_finished_queries(self, net, pipeline):
+        """``references``/``walks_skipped`` are the sums of the finished
+        queries' own QueryStats, in both schedules, and reach the
+        service snapshot's scheduler section."""
+        g, d = net
+        svc = KSPService(d, ServiceConfig(engine="pyen", n_workers=2,
+                                          pipeline=pipeline))
+        res = [svc.query(s, t, k=3) for s, t in rand_queries(g, 6, seed=57)]
+        sched = svc.snapshot()["scheduler"]
+        assert sched["completed"] == len(res)
+        assert sched["references"] == sum(r.stats.references for r in res)
+        assert sched["walks_skipped"] == sum(r.stats.walks_skipped
+                                             for r in res)
+        assert sched["walks_skipped"] > 0  # the lazy stream's walks
+        assert "idle_fracs" not in sched
+
     def test_idle_and_occupancy_stats(self, net):
         """The pipeline exports what the bench gate needs: per-worker
-        busy time against working wall time, peak in-flight batches, and
+        busy time against working wall time, peak in-flight batches,
+        reference-stream counts summed over the finished queries, and
         dedup accounting that stays an invariant of requested/dispatched."""
         g, d = net
         qs = rand_queries(g, 8, seed=51) * 2  # guaranteed overlap
         sched = QueryScheduler(Cluster(d, n_workers=4, engine="dense_bf"),
                                max_in_flight=8)
-        sched.run(qs, 3)
+        tickets = sched.run(qs, 3)
         st = sched.stats
         assert st.working_s > 0.0
         assert st.worker_busy_s and all(v >= 0.0
                                         for v in st.worker_busy_s.values())
-        fracs = st.idle_fracs()
-        assert fracs and all(0.0 <= f <= 1.0 for f in fracs.values())
+        assert st.references == sum(tk.stats.references for tk in tickets)
+        assert st.references >= len(tickets)
+        assert st.walks_skipped == sum(tk.stats.walks_skipped
+                                       for tk in tickets)
         assert st.max_inflight_batches >= 1
         assert st.batches_dispatched >= 1
         assert st.tasks_dispatched < st.tasks_requested
