@@ -43,6 +43,10 @@ class QueryStats:
     # tie-batched cohort folds many equal-weight references into one)
     walks_skipped: int = 0  # non-simple lazy-stream walks consumed for
     # the stop rule but never refined (they cannot join simply)
+    joins: int = 0  # references joined into candidates
+    joins_cut: int = 0  # of them, joins ended at the root by the cutoff
+    # at L's k-th distance, before any heap pop
+    join_pops: int = 0  # prefixes popped by the joins' best-first search
     refine_tasks: int = 0
     cache_hits: int = 0
     partial_paths: int = 0
@@ -259,69 +263,190 @@ def _partial_ksps(
     return merged[:k]
 
 
-def _k_best_joins(segments: list[list[tuple[float, tuple]]], k: int):
-    """k best simple concatenations, one entry per segment.
+class _JoinPrep:
+    """One iteration's segment lists, prepared once for every reference
+    of its cohort.
 
-    Segment j's entries run from joint v_j to v_j+1, so a join is simple
-    exactly when the joints are distinct and the entries' interiors are
-    simple, avoid every joint and are pairwise disjoint.  The search is
-    best-first over prefixes, keyed by the prefix's length plus, for
-    every remaining segment, its shortest entry that avoids the vertices
-    the prefix uses (forward checking: an admissible bound, so complete
-    joins pop in ascending length, and a prefix that leaves some segment
-    no usable entry is dropped at once).  Enumerating whole index tuples
-    instead visits up to (entries per segment)^m of them when few joins
-    are simple, which long references (m ≈ 30 on a 36×36 grid) make
-    unbounded in practice.
+    Tied references share most of their refine pairs, so each pair's
+    list is sorted and its entries' interiors turned into vertex
+    bitmasks once, on first use, and reused by every reference that
+    crosses the pair.  Bit positions are local to the cohort, so masks
+    stay as wide as the vertices the cohort's segments touch.
     """
-    m = len(segments)
-    if any(not seg for seg in segments):
-        return []
-    joints = [seg[0][1][0] for seg in segments] + [segments[-1][0][1][-1]]
-    if len(set(joints)) != len(joints):
-        return []
-    joint_set = set(joints)
-    opts = []  # per segment: ascending [(d, interior set, path)]
-    for seg in segments:
-        keep = []
+
+    def __init__(self, seg_lists):
+        self.seg_lists = seg_lists
+        self.bit: dict = {}  # vertex -> bit position
+        self.prepped: dict = {}  # pair index -> see ``segment``
+
+    def mask(self, verts) -> int:
+        bit = self.bit
+        m = 0
+        for v in verts:
+            b = bit.get(v)
+            if b is None:
+                b = bit[v] = len(bit)
+            m |= 1 << b
+        return m
+
+    def segment(self, j):
+        """Pair ``j``'s ``(a, b, D, M, P, union)``: its joints; ascending
+        by length, the length, interior mask and path of each entry whose
+        interior is simple; and the union of those masks.  None where no
+        entry has a simple interior."""
+        try:
+            return self.prepped[j]
+        except KeyError:
+            pass
+        seg = self.seg_lists[j]
+        D, M, P = [], [], []
         for d, p in sorted(seg, key=lambda e: e[0]):
-            inner = set(p[1:-1])
-            if len(inner) == len(p) - 2 and joint_set.isdisjoint(inner):
-                keep.append((d, frozenset(inner), p))
-        if not keep:
+            inner = p[1:-1]
+            if len(set(inner)) == len(inner):
+                D.append(d)
+                M.append(self.mask(inner))
+                P.append(p)
+        out = None
+        if D:
+            union = 0
+            for mi in M:
+                union |= mi
+            out = (seg[0][1][0], seg[0][1][-1], D, M, P, union)
+        self.prepped[j] = out
+        return out
+
+    def k_best_joins(self, idxs, k: int, cutoff, stats: QueryStats):
+        """The k best simple joins of the segments ``idxs``, ascending by
+        (length, path); with a ``cutoff``, only those not longer than it
+        (plus a tie slack).
+
+        Segment j's entries run from joint v_j to v_j+1, so a join is
+        simple exactly when the joints are distinct and the entries'
+        interiors are simple, avoid every joint and are pairwise
+        disjoint.  The search is best-first over prefixes, keyed by the
+        prefix's length plus, for every remaining segment, its shortest
+        entry that avoids the vertices the prefix uses (forward checking:
+        an admissible bound, so complete joins pop in ascending length,
+        and a prefix that leaves some segment no usable entry is dropped
+        at once).  The bound is kept incrementally: the suffix sum of
+        each segment's shortest usable entry, corrected only where a
+        prefix's vertices collide with a segment's current best.
+        Enumerating whole index tuples instead visits up to (entries per
+        segment)^m of them when few joins are simple, which long
+        references (m ≈ 30 on a 36×36 grid) make unbounded in practice.
+        """
+        stats.joins += 1
+        segs = [self.segment(j) for j in idxs]
+        if None in segs:
             return []
-        opts.append(keep)
-
-    def bound(j, used):
-        """Least the segments j.. can add next to ``used``; None if one
-        of them has no usable entry."""
-        total = 0.0
-        for seg in opts[j:]:
-            best = next((d for d, inner, _ in seg if used.isdisjoint(inner)),
-                        None)
-            if best is None:
-                return None
-            total += best
-        return total
-
-    heap = [(bound(0, frozenset()), (), 0.0, frozenset())]
-    out = []
-    while heap and len(out) < k:
-        _, idx, g, used = heapq.heappop(heap)
-        j = len(idx)
-        if j == m:
-            verts = list(opts[0][idx[0]][2])
-            for jj in range(1, m):
-                verts.extend(opts[jj][idx[jj]][2][1:])
-            out.append((g, tuple(verts)))
-            continue
-        for i, (d, inner, _) in enumerate(opts[j]):
-            if used.isdisjoint(inner):
-                nxt = used | inner
-                h = bound(j + 1, nxt)
-                if h is not None:
-                    heapq.heappush(heap, (g + d + h, idx + (i,), g + d, nxt))
-    return out
+        m = len(segs)
+        limit = INF
+        if cutoff is not None:
+            # the slack keeps a join tied with the cutoff, whatever the
+            # order its lengths were summed in: a tie can still win on
+            # its path
+            limit = cutoff + TIE_EPS * (1.0 + abs(cutoff))
+            # the root bound can only grow once the joint filter below
+            # drops entries: cut before paying for it
+            lb = 0.0
+            for s in segs:
+                lb += s[2][0]
+            if lb > limit:
+                stats.joins_cut += 1
+                return []
+        joints = [s[0] for s in segs] + [segs[-1][1]]
+        if len(set(joints)) != len(joints):
+            return []
+        jm = 0  # a joint no entry's interior holds has no bit
+        for v in joints:
+            b = self.bit.get(v)
+            if b is not None:
+                jm |= 1 << b
+        D, M, P = [], [], []  # per segment: the usable entries
+        for _, _, Ds, Ms, Ps, union in segs:
+            if union & jm:
+                keep = [i for i, mi in enumerate(Ms) if not mi & jm]
+                if not keep:
+                    return []
+                Ds = [Ds[i] for i in keep]
+                Ms = [Ms[i] for i in keep]
+                Ps = [Ps[i] for i in keep]
+            D.append(Ds)
+            M.append(Ms)
+            P.append(Ps)
+        # suffix sums and unions of every segment's shortest usable entry
+        S0 = [0.0] * (m + 1)
+        U0 = [0] * (m + 1)
+        for s in range(m - 1, -1, -1):
+            S0[s] = D[s][0] + S0[s + 1]
+            U0[s] = M[s][0] | U0[s + 1]
+        if S0[0] > limit:
+            stats.joins_cut += 1
+            return []
+        # a prefix of length j: (bound, entry indices, length, mask of
+        # the vertices it uses, per segment the index of its shortest
+        # entry disjoint from them — the first, for a segment the prefix
+        # does not collide with — and, ascending, the segments >= j
+        # whose index is not the first)
+        heap = [(S0[0], (), 0.0, 0, (0,) * m, ())]
+        out = []
+        pops = 0
+        while heap and len(out) < k:
+            _, idx, g, used, cur, shifted = heapq.heappop(heap)
+            pops += 1
+            j = len(idx)
+            if j == m:
+                verts = list(P[0][idx[0]])
+                for jj in range(1, m):
+                    verts.extend(P[jj][idx[jj]][1:])
+                out.append((g, tuple(verts)))
+                continue
+            nj = j + 1
+            later = shifted[1:] if shifted and shifted[0] == j else shifted
+            Dj, Mj, first = D[j], M[j], cur[j]
+            for i in range(first, len(Dj)):
+                mi = Mj[i]
+                if i > first and used & mi:
+                    continue
+                nxt = used | mi
+                cur2, shifted2 = cur, later
+                hit = mi & U0[nj]
+                if not hit:
+                    for s in later:
+                        if mi & M[s][cur[s]]:
+                            hit = 1
+                            break
+                if hit:
+                    # the entry collides with some later segment's
+                    # current best: move each such best past the
+                    # prefix's vertices, or drop the prefix if one runs out
+                    cur2 = list(cur)
+                    for s in range(nj, m):
+                        c = cur2[s]
+                        if mi & M[s][c]:
+                            Ms = M[s]
+                            c += 1
+                            while c < len(Ms) and nxt & Ms[c]:
+                                c += 1
+                            if c == len(Ms):
+                                cur2 = None
+                                break
+                            cur2[s] = c
+                    if cur2 is None:
+                        continue
+                    shifted2 = tuple(s for s in range(nj, m) if cur2[s])
+                    cur2 = tuple(cur2)
+                h = S0[nj]
+                for s in shifted2:
+                    h += D[s][cur2[s]] - D[s][0]
+                gd = g + Dj[i]
+                f = gd + h
+                if f <= limit:
+                    heapq.heappush(heap, (f, idx + (i,), gd, nxt, cur2,
+                                          shifted2))
+        stats.join_pops += pops
+        out.sort()  # tied joins pop in index order; L orders by path
+        return out
 
 
 def _next_cohort(refs, pending, batch, ref_budget, global_of_ext,
@@ -434,7 +559,8 @@ def ksp_dg_stepper(
     Traced (``repro.obs``): each contiguous run of pulls from the stream
     is a ``ref_stream`` span (attrs: ``references`` consumed,
     ``walks_skipped``), and each iteration's joins into ``L`` a ``join``
-    span (``iteration``, ``pairs``).
+    span (``iteration``, ``pairs``, and the ``joins_cut`` and
+    ``join_pops`` it added to the query's stats).
     """
     policy = variant if variant is not None else _PLAIN
     solve_k = policy.solve_k(k)
@@ -523,17 +649,29 @@ def ksp_dg_stepper(
                 # completion order, keyed by pair index — realign here
                 seg_lists = [seg_lists[j] for j in range(len(pairs))]
             with obs.span("join", iteration=stats.iterations,
-                          pairs=len(pairs)):
+                          pairs=len(pairs)) as sp:
+                cut, pops = stats.joins_cut, stats.join_pops
+                prep = _JoinPrep(seg_lists)
                 for idxs in ref_pairs:
-                    for d, p in _k_best_joins([seg_lists[j] for j in idxs],
-                                              solve_k):
+                    # a join longer than L's k-th is cut by the
+                    # truncation below anyway: no need to enumerate it.
+                    # L is kept sorted and truncated after every
+                    # reference, so the cutoff tightens within a cohort
+                    cutoff = L[-1][0] if len(L) == solve_k else None
+                    added = False
+                    for d, p in prep.k_best_joins(idxs, solve_k, cutoff,
+                                                  stats):
                         if p not in L_set:
                             L_set.add(p)
                             L.append((d, p))
-                L.sort(key=lambda x: (x[0], x[1]))
-                for d_, p_ in L[solve_k:]:
-                    L_set.discard(p_)
-                L = L[:solve_k]
+                            added = True
+                    if added:
+                        L.sort(key=lambda x: (x[0], x[1]))
+                        for _, p_ in L[solve_k:]:
+                            L_set.discard(p_)
+                        del L[solve_k:]
+                sp.set(joins_cut=stats.joins_cut - cut,
+                       join_pops=stats.join_pops - pops)
     return policy.finalize(L, k, stats, directed), stats
 
 

@@ -91,9 +91,14 @@ class BatchStats:
     working_s: float = 0.0
     worker_busy_s: dict = dataclasses.field(default_factory=dict)
     # summed over finished queries' core QueryStats: reference paths
-    # consumed, and of them the non-simple walks never refined
+    # consumed, and of them the non-simple walks never refined;
+    # references joined, of them the joins the cutoff at L's k-th ended
+    # at the root, and the joins' heap pops
     references: int = 0
     walks_skipped: int = 0
+    joins: int = 0
+    joins_cut: int = 0
+    join_pops: int = 0
 
     @property
     def tasks_deduped(self) -> int:
@@ -403,6 +408,9 @@ class QueryScheduler:
             self.stats.completed += 1
             self.stats.references += tk.stats.references
             self.stats.walks_skipped += tk.stats.walks_skipped
+            self.stats.joins += tk.stats.joins
+            self.stats.joins_cut += tk.stats.joins_cut
+            self.stats.join_pops += tk.stats.join_pops
 
     # -------------------------------------------------- pipelined serving
     def _stamp_clock(self) -> None:

@@ -228,11 +228,97 @@ def test_directed_graph_kspdg():
         assert [round(x, 8) for x, _ in got] == [round(x, 8) for x, _ in want]
 
 
+def reference_k_best_joins(segments, k):
+    """The join as it was before per-cohort prep, the incremental bound
+    and the cutoff: every segment list prepared per reference, and each
+    push's bound rescanned over every remaining segment."""
+    import heapq
+
+    m = len(segments)
+    if any(not seg for seg in segments):
+        return []
+    joints = [seg[0][1][0] for seg in segments] + [segments[-1][0][1][-1]]
+    if len(set(joints)) != len(joints):
+        return []
+    joint_set = set(joints)
+    opts = []
+    for seg in segments:
+        keep = []
+        for d, p in sorted(seg, key=lambda e: e[0]):
+            inner = set(p[1:-1])
+            if len(inner) == len(p) - 2 and joint_set.isdisjoint(inner):
+                keep.append((d, frozenset(inner), p))
+        if not keep:
+            return []
+        opts.append(keep)
+
+    def bound(j, used):
+        total = 0.0
+        for seg in opts[j:]:
+            best = next((d for d, inner, _ in seg if used.isdisjoint(inner)),
+                        None)
+            if best is None:
+                return None
+            total += best
+        return total
+
+    heap = [(bound(0, frozenset()), (), 0.0, frozenset())]
+    out = []
+    while heap and len(out) < k:
+        _, idx, g, used = heapq.heappop(heap)
+        j = len(idx)
+        if j == m:
+            verts = list(opts[0][idx[0]][2])
+            for jj in range(1, m):
+                verts.extend(opts[jj][idx[jj]][2][1:])
+            out.append((g, tuple(verts)))
+            continue
+        for i, (d, inner, _) in enumerate(opts[j]):
+            if used.isdisjoint(inner):
+                nxt = used | inner
+                h = bound(j + 1, nxt)
+                if h is not None:
+                    heapq.heappush(heap, (g + d + h, idx + (i,), g + d, nxt))
+    return out
+
+
+def k_best_joins(segments, k, cutoff=None, stats=None):
+    """The stepper's join of one reference over its own segment lists."""
+    from repro.core.kspdg import QueryStats, _JoinPrep
+
+    return _JoinPrep(segments).k_best_joins(
+        range(len(segments)), k, cutoff,
+        QueryStats() if stats is None else stats)
+
+
+class ReferenceJoin:
+    """Stands in for the stepper's per-cohort join with the reference
+    above: no shared prep, no incremental bound, no cutoff."""
+
+    def __init__(self, seg_lists):
+        self.seg_lists = seg_lists
+
+    def k_best_joins(self, idxs, k, cutoff, stats):
+        stats.joins += 1
+        return reference_k_best_joins([self.seg_lists[j] for j in idxs], k)
+
+
+def random_segment(rng, a, b, width, n_vertices, lengths=(1, 20)):
+    """≤ width distinct a→b entries with random detours, ascending."""
+    entries = set()
+    while len(entries) < width:
+        mid = rng.choice(n_vertices, rng.integers(0, 3), replace=False)
+        p = (int(a), *(int(v) for v in mid if v not in (a, b)), int(b))
+        entries.add((float(rng.integers(*lengths)), p))
+    return sorted(entries)
+
+
 class TestKBestJoins:
     """The splice of per-pair partial KSPs into whole candidates."""
 
     @staticmethod
-    def brute_force(segments, k):
+    def brute_force(segments, k=None):
+        """Every simple join, ascending by (length, path); the first k."""
         import itertools
 
         out = []
@@ -245,42 +331,157 @@ class TestKBestJoins:
         return sorted(out)[:k]
 
     @staticmethod
-    def random_segments(rng, m, width, n_vertices):
+    def random_segments(rng, m, width, n_vertices, lengths=(1, 20)):
         """m chained segments of ≤ width entries each, ascending, with
         random detours that often collide with other segments."""
         joints = rng.choice(n_vertices, m + 1, replace=False)
-        segments = []
-        for a, b in zip(joints, joints[1:]):
-            entries = set()
-            while len(entries) < width:
-                mid = rng.choice(n_vertices, rng.integers(0, 3),
-                                 replace=False)
-                p = (int(a), *(int(v) for v in mid if v not in (a, b)),
-                     int(b))
-                entries.add((float(rng.integers(1, 20)), p))
-            segments.append(sorted(entries))
-        return segments
+        return [random_segment(rng, a, b, width, n_vertices, lengths)
+                for a, b in zip(joints, joints[1:])]
 
-    @pytest.mark.parametrize("seed", range(6))
-    def test_matches_brute_force(self, seed):
-        from repro.core.kspdg import _k_best_joins
+    def joinable(self, rng, least, **kw):
+        """Random segments with at least ``least`` simple joins."""
+        while True:
+            segs = self.random_segments(rng, int(rng.integers(2, 6)), 3, 14,
+                                        **kw)
+            if len(self.brute_force(segs)) >= least:
+                return segs
+
+    def check(self, got, segs, k, cutoff=None):
+        """``got`` holds the k best simple joins not over ``cutoff``:
+        their lengths are the brute force's, each is a simple join of
+        ``segs``, and they come ascending by (length, path)."""
+        every = self.brute_force(segs)
+        want = [x for x in every[:k] if cutoff is None or x[0] <= cutoff]
+        assert [d for d, _ in got] == [d for d, _ in want]
+        assert got == sorted(got)
+        assert set(got) <= set(every)
+
+    CASES = ([pytest.param("plain", s, id=str(s)) for s in range(6)]
+             + [pytest.param(kind, s, id=f"{kind}-{s}")
+                for kind in ("cut-below", "cut-at-first", "cut-at-kth",
+                             "shared", "ties")
+                for s in range(3)]
+             + [pytest.param("collide", 0, id="collide")])
+
+    @pytest.mark.parametrize("kind, seed", CASES)
+    def test_matches_brute_force(self, kind, seed):
+        from repro.core.kspdg import QueryStats, _JoinPrep
 
         rng = np.random.default_rng(seed)
-        segs = self.random_segments(rng, int(rng.integers(1, 6)), 3, 14)
-        got = _k_best_joins(segs, 3)
-        want = self.brute_force(segs, 3)
-        assert [d for d, _ in got] == [d for d, _ in want]
-        assert all(len(set(p)) == len(p) for _, p in got)
+        if kind == "plain":
+            segs = self.random_segments(rng, int(rng.integers(1, 6)), 3, 14)
+            self.check(k_best_joins(segs, 3), segs, 3)
+        elif kind.startswith("cut-"):
+            # the cutoff: below every join, or exactly at the first or
+            # the k-th join's length, where a join tied with it is kept
+            segs = self.joinable(rng, 2)
+            want = self.brute_force(segs, 3)
+            first, kth = want[0][0], want[-1][0]
+            cutoff = {"cut-below": first - 0.5, "cut-at-first": first,
+                      "cut-at-kth": kth}[kind]
+            st = QueryStats()
+            got = k_best_joins(segs, 3, cutoff, st)
+            self.check(got, segs, 3, cutoff)
+            assert st.joins == 1
+            if kind == "cut-below":
+                assert got == [] and st.joins_cut == 1 and st.join_pops == 0
+            else:
+                assert got[-1][0] == cutoff and st.joins_cut == 0
+        elif kind == "shared":
+            # two references share their first pair, whose entries run
+            # through a vertex that is a joint of the second reference
+            # only: one preparation, filtered per reference
+            m = int(rng.integers(2, 5))
+            joints = [int(v) for v in rng.choice(14, m + 1, replace=False)]
+            seg_lists = [random_segment(rng, a, b, 3, 14)
+                         for a, b in zip(joints, joints[1:])]
+            inner = {v for _, p in seg_lists[0] for v in p[1:-1]}
+            x = min(inner - set(joints), default=None)
+            if x is None:  # no detour on the first pair: give it one
+                x = min(set(range(14)) - set(joints))
+                seg_lists[0] = sorted(seg_lists[0]
+                                      + [(1.0, (joints[0], x, joints[1]))])
+            a1, last = joints[1], joints[-1]
+            seg_lists += [random_segment(rng, a1, x, 3, 14),
+                          random_segment(rng, x, last, 3, 14)]
+            refs = [list(range(m)), [0, m, m + 1]]
+            prep = _JoinPrep(seg_lists)
+            for k in (3, 50):
+                for idxs in refs:
+                    got = prep.k_best_joins(idxs, k, None, QueryStats())
+                    self.check(got, [seg_lists[j] for j in idxs], k)
+            assert len(prep.prepped) == m + 2  # each pair prepared once
+        elif kind == "collide":
+            # the first entry moves segment 2's best past vertex 10; the
+            # second segment's shortest entry then collides with that
+            # new best (11) and not with any segment's first entry
+            segs = [[(1.0, (0, 10, 1))],
+                    [(1.0, (1, 11, 2)), (5.0, (1, 12, 2))],
+                    [(1.0, (2, 10, 3)), (2.0, (2, 11, 3)), (3.0, (2, 13, 3))]]
+            got = k_best_joins(segs, 3)
+            self.check(got, segs, 3)
+            assert [d for d, _ in got] == [5.0, 8.0, 9.0]
+        else:
+            # equal lengths, different paths: with k past every join the
+            # whole list is the brute force's, path order included
+            segs = self.joinable(rng, 4, lengths=(1, 3))
+            every = self.brute_force(segs)
+            assert len({d for d, _ in every}) < len(every)  # ties exist
+            assert k_best_joins(segs, len(every) + 1) == every
+            self.check(k_best_joins(segs, 3), segs, 3)
 
     def test_no_simple_join_returns_without_enumerating(self):
         """Every entry of the first segment runs through every detour of
         the second, so no join is simple: the answer is [] after the
         two-segment prefixes, not after 3**30 index tuples."""
-        from repro.core.kspdg import _k_best_joins
-
         m = 30
         detours = (2000 * m, 2000 * m + 1, 2000 * m + 2)
         segs = [[(1.0, (0, *detours, 100 + i, m)) for i in range(3)]]
         segs += [[(float(i + 1), (j, 2000 * j + i, j + 1)) for i in range(3)]
                  for j in range(m, 2 * m - 1)]
-        assert _k_best_joins(segs, 3) == []
+        assert k_best_joins(segs, 3) == []
+
+
+class TestJoinStepper:
+    """The stepper's joins give the answers the reference join gave,
+    under every reference stream and variant that runs them."""
+
+    @pytest.mark.parametrize("stream, variant", [
+        ("lazy", None), ("yen", None), ("lazy", "diverse"),
+        ("lazy", "bounded")])
+    def test_answers_match_reference_join(self, setup, monkeypatch, stream,
+                                          variant):
+        from repro.core import kspdg
+        from repro.core.variants import make_variant
+
+        g, d, queries = setup
+        policy = make_variant(variant)
+        k = 3
+
+        def run(s, t):
+            return ksp_dg(d, s, t, k, ref_stream=stream, variant=policy,
+                          return_stats=True)
+
+        got = [run(s, t) for s, t in queries]
+        with monkeypatch.context() as mp:
+            mp.setattr(kspdg, "_JoinPrep", ReferenceJoin)
+            want = [run(s, t) for s, t in queries]
+        view = graph_view(g)
+        for (s, t), (L, st), (L_ref, st_ref) in zip(queries, got, want):
+            assert L == L_ref, (s, t)
+            assert (st.references, st.iterations, st.joins) == (
+                st_ref.references, st_ref.iterations, st_ref.joins)
+            assert st.join_pops > 0
+            if variant is None:
+                yen = ksp(view, s, t, k)
+                assert [round(x, 8) for x, _ in L] == [
+                    round(x, 8) for x, _ in yen], (s, t)
+
+    def test_cutoff_ends_joins_at_the_root(self, setup):
+        """Once L holds k paths, a reference whose every join is longer
+        than L's k-th is cut before its search pops anything."""
+        _, d, queries = setup
+        stats = [ksp_dg(d, s, t, 3, ref_stream="lazy", return_stats=True)[1]
+                 for s, t in queries]
+        assert sum(st.joins_cut for st in stats) > 0
+        assert all(st.joins_cut <= st.joins for st in stats)

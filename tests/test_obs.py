@@ -496,6 +496,22 @@ class TestServiceObs:
         path = tmp_path / "t.json"
         assert obs.export(str(path)) == len(col.events)
 
+    def test_join_span_carries_the_join_counters(self):
+        """Each ``join`` span carries the heap pops of its joins and the
+        joins its cutoff ended at the root; over the spans they sum to
+        the queries' own stats."""
+        g, svc = build_service(engine="pyen", workers=2)
+        col = obs.enable(trace=True)
+        tickets = self._run(svc, g, n=6)
+        joins = col.spans("join")
+        assert joins
+        assert all({"joins_cut", "join_pops"} <= set(r.attrs)
+                   for r in joins)
+        for key in ("joins_cut", "join_pops"):
+            assert sum(r.attrs[key] for r in joins) == sum(
+                getattr(tk.result.stats, key) for tk in tickets), key
+        assert sum(r.attrs["join_pops"] for r in joins) > 0
+
     def test_streaming_update_emits_epoch_handoff_spans(self):
         g, svc = build_service(update_mode="streaming")
         stream = WeightUpdateStream(g, alpha=0.4, tau=0.5, seed=6)

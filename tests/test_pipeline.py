@@ -147,6 +147,21 @@ class TestPipelineStats:
         assert sched["walks_skipped"] > 0  # the lazy stream's walks
         assert "idle_fracs" not in sched
 
+    @pytest.mark.parametrize("pipeline", [False, True])
+    def test_join_counters_sum_finished_queries(self, net, pipeline):
+        """``joins``/``joins_cut``/``join_pops`` are the sums of the
+        finished queries' own QueryStats, in both schedules, and reach
+        the service snapshot's scheduler section."""
+        g, d = net
+        svc = KSPService(d, ServiceConfig(engine="pyen", n_workers=2,
+                                          pipeline=pipeline))
+        res = [svc.query(s, t, k=3) for s, t in rand_queries(g, 6, seed=57)]
+        sched = svc.snapshot()["scheduler"]
+        for key in ("joins", "joins_cut", "join_pops"):
+            assert sched[key] == sum(getattr(r.stats, key) for r in res), key
+        assert 0 <= sched["joins_cut"] <= sched["joins"]
+        assert sched["join_pops"] > 0
+
     def test_idle_and_occupancy_stats(self, net):
         """The pipeline exports what the bench gate needs: per-worker
         busy time against working wall time, peak in-flight batches,
