@@ -9,7 +9,9 @@ configuration, traffic mix and metric readers are files found by name
 service over it, offers warm-up traffic, opens the window on the host's
 clock, offers the cell's traffic for ``--seconds``, drains, reads peak
 device memory, frees the service, and compares every answer given in the
-window or drained after it with the plain reference (``oracle.py``).
+window or drained after it with the plain reference (``oracle.py``), at
+the weights of the epoch the answer carries: the harness replays the
+update batches it offered (none where the mix has no feed).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (end-to-end with ``--trace 0``,
@@ -18,9 +20,10 @@ per-layer with ``--trace 1``), ``device``, with ``--trace 1`` a
 limit.  The same numbers close standard error.  Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero before any work.
 
-``--pairs-from-seed`` draws the window's queries from ``--seed`` instead
-of the mix's fixed ``pool_seed``: the check on fresh pairs that a claimed
-gain on the query path must also pass (``PERF.md``).
+``--pairs-from-seed`` draws the window's queries, and the feed's
+batches, from ``--seed`` instead of the mix's fixed ``pool_seed`` and
+``feed_seed``: the check on fresh pairs that a claimed gain on the query
+path must also pass (``PERF.md``).
 """
 
 from __future__ import annotations
@@ -34,6 +37,7 @@ import contextlib  # noqa: E402
 import json  # noqa: E402
 import multiprocessing  # noqa: E402
 import os  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 
 import drive  # noqa: E402
@@ -149,34 +153,65 @@ def serve(api, cfg):
         traffic.stream(gspec.pop("seed"), "graph"), **gspec)
     t0 = time.perf_counter()
     svc = api.KSPService.build(
-        Graph(n, us, vs, w0),
+        Graph(n, us, vs, w0.copy()),
         api.ServiceConfig(**cfg["service"], **cfg["index"]))
     log(f"build {time.perf_counter() - t0:.3f}s | {n} vertices, "
         f"{us.shape[0]} edges")
     return (n, us, vs, w0), svc
 
 
-def check_answers(graph, w0, todo, pool, chunks=32):
-    """(fault or None, gap) per query in ``todo``, judged in a pool."""
+def check_answers(graph, weights, todo, pool, chunks=32):
+    """(fault or None, gap) per query in ``todo``, judged in a pool at
+    the weights of the epoch each answer carries (``weights[epoch]``),
+    one epoch's answers to a job, split into pieces of at most
+    1/``chunks`` of them all."""
     n, us, vs = graph
-    parts = [todo[i::chunks] for i in range(chunks)]
-    jobs = [(n, us, vs, w0, [(q.s, q.t, q.k,
-                              [(float(d), tuple(int(v) for v in p))
-                               for d, p in q.result.paths]) for q in part])
-            for part in parts if part]
+    by_epoch = {}
+    for q in todo:
+        by_epoch.setdefault(q.result.epoch, []).append(q)
+    size = -(-len(todo) // chunks)
+    parts, jobs = [], []
+    for epoch, group in sorted(by_epoch.items()):
+        pieces = -(-len(group) // size)
+        for i in range(pieces):
+            part = group[i::pieces]
+            parts.append(part)
+            jobs.append((n, us, vs, weights[epoch],
+                         [(q.s, q.t, q.k,
+                           [(float(d), tuple(int(v) for v in p))
+                            for d, p in q.result.paths]) for q in part]))
     out = {}
-    for part, res in zip([p for p in parts if p],
-                         pool.map(oracle.check_group, jobs)):
+    for part, res in zip(parts, pool.map(oracle.check_group, jobs)):
         for q, r in zip(part, res):
             out[id(q)] = r
     return [out[id(q)] for q in todo]
 
 
-def judge_run(win, graph, w0, limits, pool):
+def feed_checks(win, limits):
+    """The feed's own numbers: offered batches whose epoch no tick had
+    shown by the drain's end, and the longest time from a batch falling
+    due to the first tick at its epoch, a batch never shown counted at
+    the drain's end."""
+    lags = [(win.t_end if u.visible is None else u.visible) - u.due
+            for u in win.updates]
+    return {"updates_lost": {
+                "value": sum(1 for u in win.updates if u.visible is None),
+                "limit": limits["updates_lost"]},
+            "update_lag_s": {"value": max(lags, default=0.0),
+                             "limit": limits["update_lag_s"]}}
+
+
+def judge_run(win, graph, w0, limits, pool, feed=False):
     """Every answer the run must vouch for, against the reference: each
     one given in the window or drained after it, and each never given.
-    No update is offered, so every answer is at the first epoch."""
+    Each answer is judged at the weights of the epoch it carries: ``w0``
+    with the update batches the run offered replayed in order, the last
+    write to a road winning, as the program's coalescing commits them.
+    With a ``feed``, every offered batch must also have become visible
+    (``feed_checks``)."""
     due = win.to_judge()
+    weights = traffic.epoch_weights(
+        w0, [(u.eids, u.new_w) for u in win.updates])
     bad = []
     todo = []
     for q in due:
@@ -186,13 +221,16 @@ def judge_run(win, graph, w0, limits, pool):
         elif not (q.epoch_sub <= q.result.epoch <= q.epoch_done):
             bad.append((q, f"epoch {q.result.epoch} outside "
                            f"[{q.epoch_sub}, {q.epoch_done}]"))
+        elif not 0 <= q.result.epoch < len(weights):
+            bad.append((q, f"epoch {q.result.epoch} was never offered"))
         elif q.result.truncated:
             bad.append((q, "truncated"))
         else:
             todo.append(q)
     gap = 0.0
     ok = set()
-    for q, (fault, g) in zip(todo, check_answers(graph, w0, todo, pool)):
+    for q, (fault, g) in zip(todo, check_answers(graph, weights, todo,
+                                                  pool)):
         if fault is not None:
             bad.append((q, fault))
         else:
@@ -200,10 +238,15 @@ def judge_run(win, graph, w0, limits, pool):
             if g <= limits["dist_gap"]:
                 ok.add(id(q))
     for q, why in bad[:5]:
-        log(f"bad answer {q.s}->{q.t} k={q.k} ({q.phase}): {why}")
+        log(f"bad answer {q.s}->{q.t} k={q.k} ({q.phase}, epoch "
+            f"{None if q.result is None else q.result.epoch}): {why}")
+    log(f"answers judged at {len({q.result.epoch for q in todo})} "
+        f"distinct epochs of {len(weights)}")
     checks = {"bad_answers": {"value": len(bad),
                               "limit": limits["bad_answers"]},
               "dist_gap": {"value": gap, "limit": limits["dist_gap"]}}
+    if feed:
+        checks.update(feed_checks(win, limits))
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     failed = sum(1 for q in due if q.result is None)
     return correct, len(due), failed, ok, checks
@@ -243,20 +286,43 @@ def main():
     pool_seed = args.seed if args.pairs_from_seed else None
     warm = traffic.Phase(args.seed, "warmup", mix, n, pool_seed)
     main_ph = traffic.Phase(args.seed, "window", mix, n, pool_seed)
+    feeds = None
+    if "updates" in mix:
+        up = mix["updates"]
+        feed_seed = up["feed_seed"] if pool_seed is None else pool_seed
+        feeds = (traffic.Feed(feed_seed, "warmup", up, w0, warmup_s),
+                 traffic.Feed(feed_seed, "window", up, w0, args.seconds))
 
     win = drive.Window(args.seconds, float(mix["drain_seconds"]))
     hooks = Hooks(args.trace, compiles, obs)
     off = drive.closed_loop(svc, api, win, warm, main_ph,
-                            int(mix["clients"]), warmup_s, hooks)
+                            int(mix["clients"]), warmup_s, hooks, feeds)
     win.setup_s = win.t_open - T_START
-    t_end = time.perf_counter()
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devs)
     snap = svc.snapshot()
+    svc_stats = snap["service"]
     log(f"window {win.seconds:.3f}s | drain ended "
-        f"{t_end - win.t_close:.3f}s after the close | programs built in "
-        f"window {win.compiles} | epoch {snap['epoch']} | "
-        f"rebaselines {snap['service']['rebaselines']}")
+        f"{win.t_end - win.t_close:.3f}s after the close | programs built "
+        f"in window {win.compiles} | epoch {snap['epoch']} | "
+        f"rebaselines {svc_stats['rebaselines']}")
+    if win.updates:
+        in_win = sum(1 for u in win.updates if win.in_window(u.due))
+        late = sorted(u.offered - u.due for u in win.updates)
+        # due -> visible, a batch never visible counted at the drain's end
+        lag = sorted((win.t_end if u.visible is None else u.visible) - u.due
+                     for u in win.updates if win.in_window(u.due))
+        log(f"updates offered {len(win.updates)} ({in_win} due in the "
+            f"window; offered late by {late[len(late) // 2] * 1e3:.1f} ms "
+            f"median, {late[-1] * 1e3:.1f} ms most) "
+            f"| committed {svc_stats['update_batches']} | coalesced "
+            f"{svc_stats['coalesced_batches']} | handoff waits "
+            f"{svc_stats['handoff_waits']} | never visible "
+            f"{sum(1 for u in win.updates if u.visible is None)}")
+        if lag:
+            log(f"update lag, due to visible, of the batches due in the "
+                f"window: median {statistics.median(lag) * 1e3:.1f} ms, "
+                f"most {lag[-1] * 1e3:.1f} ms")
     if hooks.tracer is not None:
         win.trace = hooks.tracer.reduce(cfg["index"]["z"], dev.device_kind)
         obs.disable()
@@ -266,7 +332,7 @@ def main():
     ctx = multiprocessing.get_context("spawn")
     with ctx.Pool(min(8, os.cpu_count() or 1)) as pool:
         correct, attempted, failed, ok, checks = judge_run(
-            win, (n, us, vs), w0, cfg["limits"], pool)
+            win, (n, us, vs), w0, cfg["limits"], pool, feeds is not None)
     win.correct_ids = ok
     log(f"reference check of {attempted} answers "
         f"{time.perf_counter() - t0:.3f}s")

@@ -1,6 +1,12 @@
 """A whole run on the CPU at a tiny size, past the harness's look for a
 chip, with the timed path broken underneath: ``correct`` must come out
-false for each fault a cell can have, and true with none."""
+false for each fault a cell can have, and true with none.
+
+``live.feed`` is a cell of the tests' own, ``col-s`` with streaming
+updates under a live feed, since no cell of ``BENCHMARK.json`` carries
+a feed yet.  Its own faults: an update batch acknowledged, its epoch
+counted, and its weights never applied; a batch dropped, so the epoch
+never moves; every batch held back until the window has closed."""
 
 import json
 import sys
@@ -10,6 +16,15 @@ import pytest
 import run
 import spec
 
+FEED = "live.feed"
+# a tenth of the small network's roads a batch, twice a second, so that
+# a stale answer shows within a few seconds
+UPDATES = {"interval_s": 0.5, "alpha": 0.1, "tau": 0.2, "feed_seed": 5}
+# the tiny run's limit on a batch's lag: a sound run on the CPU reads
+# about 8 s, one that holds every batch back to a 16 s window's close
+# reads its warm-up and window, 20 s
+LAG_S = 12.0
+
 
 @pytest.fixture
 def tiny(monkeypatch, capsys):
@@ -17,27 +32,44 @@ def tiny(monkeypatch, capsys):
 
     monkeypatch.setattr(run, "require_chips", lambda n: jax.devices())
     monkeypatch.setattr(run, "use_compile_cache", lambda: None)
-    config, traffic = spec.config, spec.traffic
+    load, config, traffic = spec.load_benchmark, spec.config, spec.traffic
+
+    def with_feed_cell():
+        bench = load()
+        bench["workloads"].append({"name": FEED, "config": "col-s",
+                                   "traffic": "saturate", "chips": 1})
+        for m in bench["end_to_end"]:
+            if "workloads" in m:
+                m["workloads"].append(FEED)
+        return bench
 
     def small_config(bench, cell):
         cfg = config(bench, cell)
         cfg["graph"].update(rows=12, cols=12)
         cfg["index"]["z"] = 24
         cfg["service"]["engine"] = "dense_bf"  # the kernel interprets slowly
+        if cell["name"] == FEED:
+            cfg["service"]["update_mode"] = "streaming"
+            cfg["limits"].update(updates_lost=0, update_lag_s=LAG_S)
         return cfg
 
     def short_traffic(cell):
         mix = traffic(cell)
-        mix.update(warmup_seconds=1.0, drain_seconds=8.0)
+        # warm-up long enough for the CPU to compile the first buckets
+        mix.update(warmup_seconds=4.0, drain_seconds=8.0)
+        if cell["name"] == FEED:
+            mix["updates"] = dict(UPDATES)
         return mix
 
+    monkeypatch.setattr(spec, "load_benchmark", with_feed_cell)
     monkeypatch.setattr(spec, "config", small_config)
     monkeypatch.setattr(spec, "traffic", short_traffic)
 
-    def go(*extra):
+    def go(*extra, workload="col-s.saturate", seconds=3):
         monkeypatch.setattr(sys, "argv", [
-            "run.py", "--workload", "col-s.saturate", "--seed",
-            str(2**31 + 11), "--seconds", "3", "--trace", "0", *extra])
+            "run.py", "--workload", workload, "--seed",
+            str(2**31 + 11), "--seconds", str(seconds), "--trace", "0",
+            *extra])
         run.main()
         got = capsys.readouterr()
         sys.stderr.write(got.err)  # shown with a failure
@@ -106,3 +138,78 @@ def test_half_the_answers_left_out_is_caught(tiny, monkeypatch):
     monkeypatch.setattr(KSPService, "tick", lossy)
     out = tiny()
     assert out["correct"] is False and out["failed"] > 0
+
+
+def test_sound_feed_run_is_correct(tiny):
+    out = tiny(workload=FEED)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0
+    assert set(out["checks"]) == {"bad_answers", "dist_gap",
+                                  "updates_lost", "update_lag_s"}
+    assert out["metrics"]["throughput_qps"]["value"] > 0
+    assert list(out)[-1] == "checks"
+
+
+def test_feed_altered_answer_is_caught(tiny, monkeypatch):
+    def alter(tk):
+        if tk.result:
+            d, p = tk.result[-1]
+            tk.result[-1] = (d + 1.0, p)
+
+    _break_answers(monkeypatch, alter)
+    out = tiny(workload=FEED)
+    assert out["correct"] is False
+    assert out["checks"]["dist_gap"]["value"] > 1e-3
+
+
+def test_update_never_applied_is_caught(tiny, monkeypatch):
+    """The epoch advances for every batch, the weights stay as they
+    were: each answer after the first batch is stale at its stamp."""
+    from repro.dist.cluster import Cluster
+
+    apply = Cluster.apply_updates_streaming
+
+    def unapplied(self, eids, new_w, **kw):
+        return apply(self, eids, self.dtlp.graph.w[eids].copy(), **kw)
+
+    monkeypatch.setattr(Cluster, "apply_updates_streaming", unapplied)
+    out = tiny(workload=FEED)
+    assert out["correct"] is False
+
+
+def test_update_dropped_is_caught(tiny, monkeypatch):
+    """Every batch acknowledged and none applied: the epoch never moves,
+    so every answer is exact at the first epoch and only the feed's own
+    checks can see it."""
+    from repro.service import KSPService
+
+    monkeypatch.setattr(KSPService, "update", lambda self, *a, **kw: None)
+    out = tiny(workload=FEED)
+    assert out["correct"] is False
+    assert out["checks"]["updates_lost"]["value"] > 0
+
+
+def test_handoff_put_off_to_the_close_is_caught(tiny, monkeypatch):
+    """Every batch held back until the window has closed, then applied:
+    each becomes visible in the drain, too late."""
+    from repro.service import KSPService
+
+    update, close = KSPService.update, run.Hooks.window_close
+    held = []
+
+    def hold(self, batch, *, wait=True):
+        held.append((self, batch))
+
+    def release(self, win):
+        close(self, win)
+        while held:
+            svc, batch = held.pop(0)
+            update(svc, batch, wait=False)
+
+    monkeypatch.setattr(KSPService, "update", hold)
+    monkeypatch.setattr(run.Hooks, "window_close", release)
+    out = tiny(workload=FEED, seconds=16)
+    assert out["correct"] is False
+    assert out["checks"]["updates_lost"]["value"] == 0
+    lag = out["checks"]["update_lag_s"]
+    assert lag["value"] > lag["limit"]

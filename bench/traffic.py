@@ -19,6 +19,21 @@ are drawn uniformly from the network's vertices, never equal.  A mix
     warmup_seconds  the same traffic, from its own draw, offered before
                     the window opens
     drain_seconds   how long after the window an answer may still come
+    updates         optional: a live travel-time feed offered beside the
+                    queries (``Feed``); a mix without it offers none
+
+The feed block holds exactly these keys:
+
+    interval_s      seconds between batches, on the host's clock
+    alpha           share of the network's roads a batch changes; a
+                    batch changes max(1, round(alpha * roads)) distinct
+                    roads
+    tau             each changed road gets the weight
+                    max(1, round(w0 * f)), f ~ U[1 - tau, 1 + tau]:
+                    relative to the initial weights, so drift stays
+                    bounded, and whole, so sums stay exact
+    feed_seed       the batches are drawn from this fixed seed, the same
+                    for every run (the run's seed with --pairs-from-seed)
 
 Every random stream derives from a seed and a stream name, so warm-up
 and window never share draws.
@@ -26,9 +41,12 @@ and window never share draws.
 
 from __future__ import annotations
 
+import math
 import zlib
 
 import numpy as np
+
+FEED_KEYS = {"interval_s", "alpha", "tau", "feed_seed"}
 
 
 def stream(seed, name):
@@ -62,3 +80,52 @@ class Phase:
                               for lo in range(0, count, block)])
         self.s, self.t = s[idx], t[idx]
         self.k = np.full(count, int(mix["k"]), dtype=np.int64)
+
+
+class Feed:
+    """One phase's weight-update batches, in the order offered: batch
+    ``i`` is due ``i * interval_s`` after the phase opens, and
+    ``batch(i)`` is its (road ids, new weights)."""
+
+    def __init__(self, seed, name, updates, w0, seconds):
+        unknown = set(updates) - FEED_KEYS
+        missing = FEED_KEYS - set(updates)
+        if unknown or missing:
+            raise ValueError(f"feed keys: unknown {sorted(unknown)}, "
+                             f"missing {sorted(missing)}")
+        self.interval_s = float(updates["interval_s"])
+        alpha, tau = float(updates["alpha"]), float(updates["tau"])
+        if self.interval_s <= 0 or not 0 < alpha <= 1 or not 0 <= tau < 1:
+            raise ValueError(f"feed out of range: {updates}")
+        w0 = np.asarray(w0, dtype=np.float64)
+        m = w0.shape[0]
+        size = max(1, round(alpha * m))
+        rng = stream(seed, name + ".updates")
+        self.eids, self.new_w = [], []
+        for _ in range(math.ceil(float(seconds) / self.interval_s)):
+            e = np.sort(rng.choice(m, size, replace=False)).astype(np.int64)
+            f = rng.uniform(1.0 - tau, 1.0 + tau, size)
+            self.eids.append(e)
+            self.new_w.append(np.maximum(1.0, np.round(w0[e] * f)))
+
+    def __len__(self):
+        return len(self.eids)
+
+    def due(self, i):
+        """Seconds after the phase opens at which batch ``i`` is due."""
+        return i * self.interval_s
+
+    def batch(self, i):
+        return self.eids[i], self.new_w[i]
+
+
+def epoch_weights(w0, batches):
+    """The weights at every epoch: ``out[e]`` is ``w0`` with the first
+    ``e`` of ``batches`` ((road ids, new weights), in the order offered)
+    applied, the last write to a road winning."""
+    w = np.array(w0, dtype=np.float64)
+    out = [w.copy()]
+    for eids, new_w in batches:
+        w[eids] = new_w
+        out.append(w.copy())
+    return out

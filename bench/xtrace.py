@@ -2,8 +2,9 @@
 
 ``Tracer`` runs JAX's profiler with the Python tracer off and the host
 tracer at its lowest level, inside one ``bench_window`` annotation, and
-lets the driver annotate what the host is doing (``submit``, ``tick``).  ``reduce_profile`` turns the ``.xplane.pb`` into
-a ``DeviceTrace``:
+lets the driver annotate what the host is doing (``submit``, ``tick``,
+``update``).  ``reduce_profile`` turns the ``.xplane.pb`` into a
+``DeviceTrace``:
 
     window     the ``bench_window`` annotation's interval
     busy       the union of the device's ``XLA Ops`` intervals inside the
@@ -32,7 +33,7 @@ import tempfile
 from roofline import relax_least_s
 
 WINDOW = "bench_window"
-HOST_MARKS = ("submit", "tick")
+HOST_MARKS = ("submit", "tick", "update")
 KERNEL = "bf_relax"
 _SHAPE = re.compile(r"=\s*f32\[(\d+),(\d+),(\d+)\]")
 
